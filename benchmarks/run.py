@@ -518,7 +518,6 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import AllToAll, CommSpec
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import shard_map
 
 PDEV, C, D, REPS = 8, %(C)d, %(D)d, 10
 mesh = make_mesh((PDEV,), ("model",))
@@ -536,7 +535,7 @@ def timeit(fn):
 
 def pair(group):
     op = AllToAll(CommSpec(axis="model", axis_size=PDEV, group_size=group))
-    f = shard_map(lambda v, m: (op(v), op(m[..., None])[..., 0]), mesh=mesh,
+    f = jax.shard_map(lambda v, m: (op(v), op(m[..., None])[..., 0]), mesh=mesh,
                   in_specs=(P("model"), P("model")),
                   out_specs=(P("model"), P("model")), check_vma=False)
     return jax.jit(f)
@@ -544,7 +543,7 @@ def pair(group):
 
 def fused(group):
     op = AllToAll(CommSpec(axis="model", axis_size=PDEV, group_size=group))
-    f = shard_map(lambda v, m: op.fused(v, m), mesh=mesh,
+    f = jax.shard_map(lambda v, m: op.fused(v, m), mesh=mesh,
                   in_specs=(P("model"), P("model")),
                   out_specs=(P("model"), P("model")), check_vma=False)
     return jax.jit(f)
@@ -580,6 +579,8 @@ def collectives(fast=False):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # Forced host devices: the child must never contend for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + env.get(
         "PYTHONPATH", ""
@@ -619,7 +620,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.models import moe as moe_mod
 from repro.models.config import ModelConfig, MoEConfig
 from repro.parallel.sharding import make_plan
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
@@ -638,7 +639,7 @@ def timeit(fn, *a):
     return (time.perf_counter() - t0) / REPS * 1e6
 
 entry = {"bench": "overlap", "devices": 8, "seq": %(SEQ)d, "d_ff": %(DFF)d}
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     outs = {}
     for c in (1, 2, 4):
         cfg_c = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, overlap_chunks=c))
@@ -670,6 +671,8 @@ def overlap(fast=False):
     # --- (a) execution side: subprocess on 8 forced devices ----------------
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # Forced host devices: the child must never contend for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + env.get(
         "PYTHONPATH", ""

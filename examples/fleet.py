@@ -20,6 +20,7 @@ sys.path.insert(0, "src")
 import jax
 
 from repro.configs import ARCH_NAMES, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_model
 from repro.obs import metrics, trace
 from repro.parallel.sharding import make_plan
@@ -61,6 +62,7 @@ def main():
     ap.add_argument("--metrics", default="",
                     help="dump the metrics-registry snapshot to this path")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace:
         trace.enable()
 

@@ -13,6 +13,7 @@ sys.path.insert(0, "src")
 import numpy as np
 
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ModelConfig, MoEConfig
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--backend", choices=("mixnet", "einsum"), default="mixnet")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ModelConfig(
         name="quickstart-moe",

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_model
 from repro.obs import metrics, trace
 from repro.parallel.sharding import make_plan
@@ -57,6 +58,7 @@ def main():
     ap.add_argument("--metrics", default="",
                     help="dump the metrics-registry snapshot to this path")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace:
         trace.enable()
 

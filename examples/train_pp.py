@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig, MoEConfig
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan, virtual_experts
@@ -81,6 +82,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     if jax.device_count() < STAGES * EP:
         raise SystemExit(
             f"needs {STAGES * EP} devices, have {jax.device_count()} "
@@ -97,20 +99,20 @@ def main():
 
     print(f"== PP(S={STAGES}) x EP({EP}) on {jax.device_count()} host "
           f"devices, microbatches=2, vs the flat EP({EP}) step ==")
-    with use_mesh(pp_mesh):
+    with jax.set_mesh(pp_mesh):
         pp_step = jax.jit(make_pp_train_step(
             CFG, pp_plan, OPT, pp_mesh, pp_stages=STAGES, microbatches=2))
-    with use_mesh(ref_mesh):
+    with jax.set_mesh(ref_mesh):
         ref_step = jax.jit(make_train_step(
             CFG, ref_plan, OPT, mesh=ref_mesh, microbatches=2))
 
     for step in range(args.steps):
         batch = batch_for(step)
         perm, wire = plan_for_step(step)
-        with use_mesh(pp_mesh):
+        with jax.set_mesh(pp_mesh):
             params, opt_state, m = pp_step(
                 params, opt_state, batch, perm, wire)
-        with use_mesh(ref_mesh):
+        with jax.set_mesh(ref_mesh):
             ref_params, ref_opt, rm = ref_step(
                 ref_params, ref_opt, batch, perm, wire)
         loss, ref_loss = float(m["loss"]), float(rm["loss"])

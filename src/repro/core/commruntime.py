@@ -81,14 +81,6 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size; ``lax.psum(1, axis)`` constant-folds on jax
-    releases predating ``lax.axis_size``."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _grid_groups(p: int, group_size: int) -> tuple[list[list[int]], list[list[int]]]:
     if p % group_size != 0:
         raise ValueError(f"axis size {p} not divisible by group size {group_size}")
@@ -106,7 +98,7 @@ def flat_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
 def _a2a_scale_up(x: jax.Array, axis_name: str, group_size: int) -> jax.Array:
     """Delegation stage 1 — intra-group exchange (the scale-up/NVSwitch
     analogue): split/concat the h-chunk dim.  ``[P, ...]`` in and out."""
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     h = group_size
     g = p // h
     intra, _ = _grid_groups(p, h)
@@ -118,7 +110,7 @@ def _a2a_scale_up(x: jax.Array, axis_name: str, group_size: int) -> jax.Array:
 def _a2a_scale_out(x: jax.Array, axis_name: str, group_size: int) -> jax.Array:
     """Delegation stage 2 — inter-group exchange (the scale-out/OCS
     analogue): split/concat the g-chunk dim.  ``[P, ...]`` in and out."""
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     h = group_size
     g = p // h
     _, inter = _grid_groups(p, h)
@@ -144,7 +136,7 @@ def hierarchical_all_to_all(
       through :meth:`AllToAll.stages` so the overlap scheduler can run
       another chunk's compute between them.
     """
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     h = group_size
     if p == 1 or h == 1 or h >= p:
         return flat_all_to_all(x, axis_name)
@@ -193,7 +185,7 @@ def hierarchical_psum(
     by a factor of the inner axis size versus a flat all-reduce.  Scalars and
     shapes the inner axis does not divide fall back to the flat psum.
     """
-    inner = _axis_size(inner_axis)
+    inner = lax.axis_size(inner_axis)
     if inner == 1 or x.ndim == 0 or x.shape[scatter_dim] % inner != 0:
         y = lax.psum(x, inner_axis)
         return lax.psum(y, outer_axis) if outer_axis else y
@@ -206,7 +198,7 @@ def hierarchical_psum(
 def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     """Explicit ring all-gather via collective_permute (comm/compute overlap
     building block for the perf path; semantically = lax.all_gather(tiled))."""
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -237,7 +229,7 @@ def ring_reduce_scatter(
     ``lax.psum_scatter(tiled=True)`` in ring order (f32 summation order
     differs from XLA's tree, so equality is allclose, exact for ints).
     """
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p == 1:
         return x
     idx = lax.axis_index(axis_name)

@@ -29,6 +29,7 @@ devices.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import numpy as np
@@ -131,6 +132,21 @@ class RegionGateStats:
         self.alpha = float(state.get("alpha", self.alpha))
 
 
+@functools.cache
+def _slot_gather(sharding):
+    """Jitted ``leaf[l, rows[l, s]]`` gather whose result keeps the mesh
+    ``sharding``: an expert-sharded leaf comes back expert-sharded (the gather
+    runs as a collective), where an eager gather would replicate it on every
+    device."""
+    import jax
+    import jax.numpy as jnp
+
+    def gather(leaf, rows):
+        return leaf[jnp.arange(rows.shape[0])[:, None], rows]
+
+    return jax.jit(gather, out_shardings=sharding)
+
+
 def permute_expert_weights(params, inv_stack: np.ndarray, num_virtual: int):
     """Gather every MoE block's stacked expert tensors into their new slots.
 
@@ -141,7 +157,9 @@ def permute_expert_weights(params, inv_stack: np.ndarray, num_virtual: int):
     reconfiguration, mirrored by the router-side ``perm_stack`` composition
     in :meth:`ControlPlane.apply`.
     """
-    import jax.numpy as jnp  # lazy: pure-simulation consumers stay jax-free
+    # lazy: pure-simulation consumers stay jax-free
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
 
     reps = inv_stack.shape[0]
     rows = jnp.asarray(inv_stack)
@@ -149,6 +167,8 @@ def permute_expert_weights(params, inv_stack: np.ndarray, num_virtual: int):
 
     def permute(leaf):
         if leaf.ndim >= 2 and leaf.shape[0] == reps and leaf.shape[1] == num_virtual:
+            if isinstance(leaf.sharding, NamedSharding):
+                return _slot_gather(leaf.sharding)(leaf, rows)
             return leaf[gather_idx]
         return leaf
 
@@ -622,8 +642,10 @@ class PlacementApplier:
         self.wire_perm: np.ndarray | None = None
         self.wire_reconfig_count = 0
 
-    def apply(self, params, plans: list[LayerPlan]):
-        """Actuate ``plans``; returns ``(params, changed)``."""
+    def apply(self, params, plans: list[LayerPlan], states=()):
+        """Actuate ``plans``; returns ``(params, changed)``.  ``states`` are
+        param-shaped trees (optimizer moments) whose expert tensors a weight
+        gather permutes in place alongside ``params``."""
         from repro.core.commruntime import device_perm_from_slots
 
         cp = self.cp
@@ -671,6 +693,8 @@ class PlacementApplier:
             metrics.counter("placement.applies", mode="weight_gather").inc()
         if gather_needed:
             params = permute_expert_weights(params, inv_stack, ev)
+            for tree in states:
+                permute_expert_weights(tree, inv_stack, ev)
         for p in live:
             cp.apply(p)
         return params, True

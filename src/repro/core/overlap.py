@@ -184,10 +184,10 @@ def ring_gather_leaf(
     untouched when the dim does not divide the axis (matching how the init
     specs shard conditionally).
     """
+    import jax
     from jax.sharding import PartitionSpec as P
 
     from repro.core.commruntime import AllGather, CommSpec
-    from repro.parallel.sharding import shard_map
 
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     fsize = sizes.get(fsdp_axis, 1)
@@ -205,7 +205,7 @@ def ring_gather_leaf(
     out_axes = list(in_axes)
     out_axes[fsdp_dim] = None
     ag = AllGather(CommSpec(axis=fsdp_axis, axis_size=fsize), impl="ring")
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda v: ag(v, axis=fsdp_dim),
         mesh=mesh,
         in_specs=P(*in_axes),

@@ -186,9 +186,9 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # [C, D]
-    k = k_ref[0, :, 0, :]  # [page, D]
-    v = v_ref[0, :, 0, :]
+    q = q_ref[0].astype(jnp.float32) * scale  # [C, D]
+    k = k_ref[0]  # [page, D]
+    v = v_ref[0]
     s = jnp.dot(q, k.astype(jnp.float32).T, preferred_element_type=jnp.float32)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
@@ -217,7 +217,7 @@ def _paged_decode_kernel(
     @pl.when(p == pages - 1)
     def _store():
         denom = jnp.where(l_ref[...] > 0.0, l_ref[...], 1.0)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -243,6 +243,13 @@ def paged_flash_decode_pallas(
     index maps read ``table[b, p]`` from SMEM to aim each page's DMA, so an
     arbitrary slot-length mix streams through one static grid
     ``(B, Hq, P)`` with no gather materialized in HBM.
+
+    Heads are addressed through free row-major views that merge them into
+    the last dim (``q [B, C, Hq*D]``, pools ``[N, page, Hkv*D]``): a one-head
+    block is then ``(C, D)`` / ``(page, D)``, which meets the TPU tiling rule
+    (last two block dims divisible by (8, 128) or equal to the array's) for
+    ``D % 128 == 0``, where a ``(1, D)`` head slice of the 4-D layout does
+    not.
     """
     b, c, hq, d = q.shape
     n_pages, page, hkv, _ = k_pool.shape
@@ -279,22 +286,22 @@ def paged_flash_decode_pallas(
         num_scalar_prefetch=2,
         grid=(b, hq, pages),
         in_specs=[
-            pl.BlockSpec((1, c, 1, d), lambda bi, hi, pi, tref, lref: (bi, 0, hi, 0)),
+            pl.BlockSpec((1, c, d), lambda bi, hi, pi, tref, lref: (bi, 0, hi)),
             pl.BlockSpec(
-                (1, page, 1, d),
+                (1, page, d),
                 lambda bi, hi, pi, tref, lref: (
-                    jnp.maximum(tref[bi, pi], 0), 0, hi // group, 0
+                    jnp.maximum(tref[bi, pi], 0), 0, hi // group
                 ),
             ),
             pl.BlockSpec(
-                (1, page, 1, d),
+                (1, page, d),
                 lambda bi, hi, pi, tref, lref: (
-                    jnp.maximum(tref[bi, pi], 0), 0, hi // group, 0
+                    jnp.maximum(tref[bi, pi], 0), 0, hi // group
                 ),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, c, 1, d), lambda bi, hi, pi, tref, lref: (bi, 0, hi, 0)
+            (1, c, d), lambda bi, hi, pi, tref, lref: (bi, 0, hi)
         ),
         scratch_shapes=[
             pltpu.VMEM((c, 1), jnp.float32),
@@ -305,13 +312,14 @@ def paged_flash_decode_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, c, hq * d), q.dtype),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),
         lengths.astype(jnp.int32),
-        q,
-        k_pool,
-        v_pool,
+        q.reshape(b, c, hq * d),
+        k_pool.reshape(n_pages, page, hkv * d),
+        v_pool.reshape(n_pages, page, hkv * d),
     )
+    out = out.reshape(b, c, hq, d)
     return out[:, :c_in] if c_in != c else out

@@ -12,10 +12,12 @@ Both kernels drive the gather with **scalar-prefetched** index arrays
 (``pltpu.PrefetchScalarGridSpec``): the index map of the data input reads the
 packed-row/source-row id from SMEM before the block DMA is issued, so the
 pipeline streams exactly the rows it needs from HBM — no one-hot matrices,
-no host-side reordering.  Row blocks are single token rows ``(1, D)``; the
-grid walks packed rows (dispatch) or (token, choice) pairs (combine), and
-the combine accumulates its S terms in a VMEM scratch like the grouped GEMM
-accumulates its K steps.
+no host-side reordering.  Row blocks are single token rows; the grid walks
+packed rows (dispatch) or (token, choice) pairs (combine), and the combine
+accumulates its S terms in a VMEM scratch like the grouped GEMM accumulates
+its K steps.  Rows are addressed through a ``[rows, 1, D]`` view so that a
+one-row block ``(1, 1, D)`` meets the TPU tiling rule (the last two block
+dims equal the array's), which a ``(1, D)`` block of ``[rows, D]`` does not.
 
 TPU is the target; CPU validation runs in ``interpret=True`` mode against
 :func:`repro.kernels.ref.moe_dispatch` / :func:`repro.kernels.ref.moe_combine`.
@@ -56,16 +58,19 @@ def moe_dispatch_pallas(
         num_scalar_prefetch=1,
         grid=(p,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, src_ref: (jnp.maximum(src_ref[i], 0), 0))
+            pl.BlockSpec(
+                (1, 1, d), lambda i, src_ref: (jnp.maximum(src_ref[i], 0), 0, 0)
+            )
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, src_ref: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, src_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _dispatch_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((p, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((p, 1, d), x.dtype),
         interpret=interpret,
-    )(src.astype(jnp.int32), x)
+    )(src.astype(jnp.int32), x.reshape(t, 1, d))
+    return out.reshape(p, d)
 
 
 def _combine_kernel(slot_ref, w_ref, y_ref, o_ref, acc_ref, *, s_steps: int):
@@ -78,11 +83,11 @@ def _combine_kernel(slot_ref, w_ref, y_ref, o_ref, acc_ref, *, s_steps: int):
 
     i = ti * s_steps + si
     w = jnp.where(slot_ref[i] >= 0, w_ref[i], 0.0)
-    acc_ref[...] += w * y_ref[...].astype(jnp.float32)
+    acc_ref[...] += w * y_ref[0].astype(jnp.float32)
 
     @pl.when(si == s_steps - 1)
     def _store():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -101,23 +106,27 @@ def moe_combine_pallas(
         grid=(t, s),
         in_specs=[
             pl.BlockSpec(
-                (1, d),
+                (1, 1, d),
                 lambda ti, si, slot_ref, w_ref: (
                     jnp.maximum(slot_ref[ti * s + si], 0),
+                    0,
                     0,
                 ),
             )
         ],
-        out_specs=pl.BlockSpec((1, d), lambda ti, si, slot_ref, w_ref: (ti, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, d), lambda ti, si, slot_ref, w_ref: (ti, 0, 0)
+        ),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_combine_kernel, s_steps=s),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((t, 1, d), jnp.float32),
         interpret=interpret,
     )(
         slot.reshape(-1).astype(jnp.int32),
         weights.reshape(-1).astype(jnp.float32),
-        y,
+        y.reshape(p, 1, d),
     )
+    return out.reshape(t, d)

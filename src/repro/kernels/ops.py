@@ -7,11 +7,23 @@ Each op dispatches between the Pallas TPU kernel and the pure-jnp reference:
     the reference path for speed).
   * ``backend="pallas"`` — force the kernel (interpret=True off-TPU).
   * ``backend="ref"``   — force the oracle.
+
+Pallas kernels have no autodiff rule, so every kernel on the training path
+(``flash_attention``, ``grouped_matmul`` in both layouts, ``moe_dispatch``,
+``moe_combine``, ``topk_gating``) runs as a ``jax.custom_vjp``: the kernel is
+the forward and the backward runs in XLA.  The backward is the VJP of the
+op's :mod:`repro.kernels.ref` oracle, which shares the kernel's semantics;
+the dropless grouped GEMM alone has a hand-written XLA backward of the same
+math (see :func:`_gmm_blocks_bwd`).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import (
@@ -47,44 +59,103 @@ def _resolve_simple(backend: str) -> str:
     return "ref" if mode == "chunked" else mode
 
 
+def _with_vjp(kernel, bwd):
+    """``kernel(*args)`` as a ``custom_vjp`` whose backward is
+    ``bwd(args, cotangent)``; the residuals are the primal args."""
+    f = jax.custom_vjp(lambda *args: kernel(*args))
+    f.defvjp(lambda *args: (kernel(*args), args), bwd)
+    return f
+
+
+def _with_ref_vjp(kernel, oracle):
+    """``kernel`` forward; backward = the VJP of its ``ref.py`` ``oracle``
+    (integer index args get the oracle's float0 cotangents)."""
+    return _with_vjp(kernel, lambda args, g: jax.vjp(oracle, *args)[1](g))
+
+
+def _gmm_blocks_bwd(args, g):
+    """XLA backward of :func:`ref.grouped_matmul_blocks`.
+
+    The oracle's own VJP differentiates a ``lax.scan`` that gathers one
+    expert's weights per row tile, and would stack an f32 ``[D, F]`` weight
+    residual per tile (hundreds of GB at published widths).  Here the tile
+    ownership becomes a one-hot ``[n, E]`` mask, so each gradient is one
+    contraction: ``dx[n] = g[n] @ w[e(n)]^T`` and
+    ``dw[e] = sum over tiles owned by e of x[n]^T g[n]``, both in f32."""
+    x, w, block_experts = args
+    onehot = (
+        block_experts[:, None] == jnp.arange(w.shape[0], dtype=jnp.int32)
+    ).astype(jnp.float32)  # [n, E]
+    f32 = jnp.float32
+    xe = jnp.einsum("nbd,ne->nbed", x.astype(f32), onehot)
+    dw = jnp.einsum("nbed,nbf->edf", xe, g.astype(f32))
+    ge = jnp.einsum("nbf,edf->nbed", g.astype(f32), w.astype(f32))
+    dx = jnp.einsum("nbed,ne->nbd", ge, onehot)
+    return (
+        dx.astype(x.dtype),
+        dw.astype(w.dtype),
+        np.zeros(block_experts.shape, jax.dtypes.float0),
+    )
+
+
 def grouped_matmul(x, w, *, block_experts=None, backend: str = "auto"):
     """Per-expert GEMM.  ``block_experts=None``: capacity layout ``[E, C, D]``
     against ``w [E, D, F]``; with a ``[n]`` block->expert map: dropless block
-    layout ``[n, B, D]`` (rows of tile ``i`` use ``w[block_experts[i]]``)."""
+    layout ``[n, B, D]`` (rows of tile ``i`` use ``w[block_experts[i]]``).
+    Pallas path: backward in XLA (the capacity layout through the oracle's
+    VJP, the block layout through :func:`_gmm_blocks_bwd`)."""
     mode = _resolve_simple(backend)
+    interpret = not on_tpu()
     if block_experts is None:
         if mode == "pallas":
-            return grouped_matmul_pallas(x, w, interpret=not on_tpu())
+            return _with_ref_vjp(
+                functools.partial(grouped_matmul_pallas, interpret=interpret),
+                ref.grouped_matmul,
+            )(x, w)
         return ref.grouped_matmul(x, w)
     if mode == "pallas":
-        return grouped_matmul_blocks_pallas(
-            x, w, block_experts, interpret=not on_tpu()
-        )
+        return _with_vjp(
+            functools.partial(grouped_matmul_blocks_pallas, interpret=interpret),
+            _gmm_blocks_bwd,
+        )(x, w, block_experts)
     return ref.grouped_matmul_blocks(x, w, block_experts)
 
 
 def moe_dispatch(x, src, *, backend: str = "auto"):
     """Gather token rows ``x [T, D]`` into a packed layout by ``src [P]``
-    (i32 source row per slot, -1 = empty -> zeros)."""
+    (i32 source row per slot, -1 = empty -> zeros).  Pallas path: backward
+    is the VJP of :func:`ref.moe_dispatch` in XLA."""
     mode = _resolve_simple(backend)
     if mode == "pallas":
-        return moe_dispatch_pallas(x, src, interpret=not on_tpu())
+        return _with_ref_vjp(
+            functools.partial(moe_dispatch_pallas, interpret=not on_tpu()),
+            ref.moe_dispatch,
+        )(x, src)
     return ref.moe_dispatch(x, src)
 
 
 def moe_combine(y, slot, weights, *, backend: str = "auto"):
     """Weighted combine of packed rows ``y [P, D]`` back to token order via
-    ``slot``/``weights [T, S]`` (f32 result; ``slot < 0`` terms skipped)."""
+    ``slot``/``weights [T, S]`` (f32 result; ``slot < 0`` terms skipped).
+    Pallas path: backward is the VJP of :func:`ref.moe_combine` in XLA."""
     mode = _resolve_simple(backend)
     if mode == "pallas":
-        return moe_combine_pallas(y, slot, weights, interpret=not on_tpu())
+        return _with_ref_vjp(
+            functools.partial(moe_combine_pallas, interpret=not on_tpu()),
+            ref.moe_combine,
+        )(y, slot, weights)
     return ref.moe_combine(y, slot, weights)
 
 
 def topk_gating(logits, k: int, *, backend: str = "auto"):
+    """Softmax + top-k router.  Pallas path: backward is the VJP of
+    :func:`ref.topk_gating` in XLA."""
     mode = _resolve_simple(backend)
     if mode == "pallas":
-        return topk_gating_pallas(logits, k, interpret=not on_tpu())
+        return _with_ref_vjp(
+            functools.partial(topk_gating_pallas, k=k, interpret=not on_tpu()),
+            functools.partial(ref.topk_gating, k=k),
+        )(logits)
     return ref.topk_gating(logits, k)
 
 
@@ -98,12 +169,17 @@ def flash_attention(
     softcap: float | None = None,
     backend: str = "auto",
 ):
+    """GQA attention ``q [B, Hq, S, D]``, ``k/v [B, Hkv, S, D]``.  Pallas
+    path: backward is the VJP of :func:`ref.flash_attention` in XLA."""
     mode = _resolve(backend)
     if mode == "pallas":
-        return flash_attention_pallas(
-            q, k, v, causal=causal, window=window, softcap=softcap,
-            interpret=not on_tpu(),
-        )
+        kw = dict(causal=causal, window=window, softcap=softcap)
+        return _with_ref_vjp(
+            functools.partial(
+                flash_attention_pallas, interpret=not on_tpu(), **kw
+            ),
+            functools.partial(ref.flash_attention, **kw),
+        )(q, k, v)
     if mode == "ref":
         return ref.flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap
@@ -165,9 +241,6 @@ def ssd_chunk(x, da, bmat, cmat, *, backend: str = "auto"):
         from repro.kernels.ssd_chunk import ssd_chunk_pallas
 
         return ssd_chunk_pallas(x, da, bmat, cmat, interpret=not on_tpu())
-    import jax.numpy as jnp
-    import numpy as np
-
     l = x.shape[1]
     cum = jnp.cumsum(da, axis=1)
     cb = jnp.einsum("gln,gsn->gls", cmat, bmat)

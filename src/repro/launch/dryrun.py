@@ -32,7 +32,7 @@ import numpy as np
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.shapes import SHAPES, cell_supported
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import HW, analytic_hbm_bytes, roofline_from_counts
 from repro.launch.specs import make_cell
 
@@ -71,7 +71,7 @@ def run_cell(
     t0 = time.time()
     fn, args = make_cell(cfg, shape, mesh, microbatches=microbatches)
     donate = getattr(fn, "donate_argnums", ())
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

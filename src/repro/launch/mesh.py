@@ -13,35 +13,16 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh", "use_mesh", "make_production_mesh", "make_test_mesh"]
-
-
-def use_mesh(mesh):
-    """Version-compatible ``jax.set_mesh`` context manager.
-
-    Newer jax exposes ``jax.set_mesh``/``jax.sharding.use_mesh``; on older
-    releases the ``Mesh`` object itself is the context manager that installs
-    the thread-local mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+__all__ = ["make_mesh", "make_production_mesh", "make_test_mesh"]
 
 
 def make_mesh(shape, axes):
-    """Version-compatible ``jax.make_mesh``.
-
-    Newer jax wants explicit ``axis_types`` (Auto) for shard_map + pjit
-    mixing; older releases predate ``jax.sharding.AxisType`` and default to
-    the same behavior.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``: shard_map regions and
+    pjit sharding propagation mix on the same mesh (jax's own default is
+    ``Explicit``)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
 from repro.data.pipeline import FileLM, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
 from repro.train.trainer import Trainer, TrainerConfig
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.moe_backend and cfg.is_moe:

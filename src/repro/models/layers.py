@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
-from repro.parallel.sharding import shard_map
 
 Params = dict
 Specs = dict
@@ -244,6 +243,24 @@ def _paged_decode_attention(
     return out, new_cache
 
 
+def _flash_per_head_shard(q, k, v, mesh, hspec, *, backend, window=None, softcap=None):
+    """Causal flash attention on ``[B, H, S, D]`` tensors.  On the TPU, with
+    a head sharding ``hspec``, it runs per device on the local head shard: XLA
+    cannot partition a Mosaic kernel, so the kernel needs the ``shard_map``.
+    XLA partitions the jnp paths taken elsewhere itself."""
+    def attend(q, k, v):
+        return ops.flash_attention(
+            q, k, v, causal=True, window=window, softcap=softcap, backend=backend
+        )
+
+    if hspec is None or not ops.on_tpu():
+        return attend(q, k, v)
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=(hspec, hspec, hspec), out_specs=hspec,
+        check_vma=False,
+    )(q, k, v)
+
+
 def attention_apply(
     params: Params,
     x: jax.Array,  # [B, S, D]
@@ -313,6 +330,7 @@ def attention_apply(
         # SP -> TP reshard: attention runs head-sharded over the model axis
         # (one all-to-all in, one out) — without this the partitioner keeps
         # seq sharding and re-gathers full K/V inside every q-chunk step.
+        hspec = None
         if plan is not None and mesh is not None and plan.heads_axis(h):
             batch_ok = b % max(plan.data_size, 1) == 0
             hspec = P(
@@ -322,12 +340,9 @@ def attention_apply(
             qt = constrain(qt, mesh, hspec)
             kt = constrain(kt, mesh, hspec)
             vt = constrain(vt, mesh, hspec)
-        out = ops.flash_attention(
-            qt, kt, vt,
-            causal=True,
-            window=window,
-            softcap=cfg.logit_softcap,
-            backend=attn_backend,
+        out = _flash_per_head_shard(
+            qt, kt, vt, mesh, hspec,
+            window=window, softcap=cfg.logit_softcap, backend=attn_backend,
         ).transpose(0, 2, 1, 3)
         if (
             cfg.sp_shardmap
@@ -497,6 +512,7 @@ def mla_attention_apply(
         # SP -> TP reshard (same as attention_apply): without this the
         # q-chunk scan re-gathers full-sequence K every step — the single
         # biggest collective in the deepseek-v2 baseline (§Perf).
+        hspec = None
         if plan is not None and mesh is not None and plan.heads_axis(h):
             batch_ok = b % max(plan.data_size, 1) == 0
             hspec = P(
@@ -506,10 +522,8 @@ def mla_attention_apply(
             qt = constrain(qt, mesh, hspec)
             kt = constrain(kt, mesh, hspec)
             vt = constrain(vt, mesh, hspec)
-        out = ops.flash_attention(
-            qt, kt, vt,
-            causal=True,
-            backend=attn_backend,
+        out = _flash_per_head_shard(
+            qt, kt, vt, mesh, hspec, backend=attn_backend,
         ).transpose(0, 2, 1, 3)[..., : m.v_head_dim]
         if mode == "prefill":
             cache = {"ckv": ckv, "k_rope": k_rope[:, :, 0, :]}
@@ -591,7 +605,7 @@ def mlp_apply_sp(params: Params, x: jax.Array, cfg, plan, mesh) -> jax.Array:
         return jax.lax.psum_scatter(y_part, "model", scatter_dimension=1, tiled=True)
 
     w_gate = params.get("w_gate", params["w_in"])
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -628,7 +642,7 @@ def oproj_sp(out: jax.Array, wo: jax.Array, plan, mesh) -> jax.Array:
         y_part = jnp.einsum("bshk,hkd->bsd", o, w)
         return jax.lax.psum_scatter(y_part, "model", scatter_dimension=1, tiled=True)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

@@ -77,7 +77,7 @@ from repro.core.commruntime import AllToAll, CommSpec, fuse_pack, fuse_unpack
 from repro.kernels import ops
 from repro.models import routing
 from repro.models.routing import MoEStats, router_losses
-from repro.parallel.sharding import ShardingPlan, constrain, shard_map, virtual_experts
+from repro.parallel.sharding import ShardingPlan, constrain, virtual_experts
 
 __all__ = [
     "init_moe", "moe_apply", "MoEStats", "router_losses",
@@ -549,7 +549,7 @@ def _moe_mixnet(
                 token_axes=token_axes,
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             wrapped, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
             check_vma=False,
         )

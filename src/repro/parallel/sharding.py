@@ -20,7 +20,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["ShardingPlan", "make_plan", "virtual_experts", "shard_map"]
+__all__ = ["ShardingPlan", "make_plan", "virtual_experts"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,21 +116,3 @@ def constrain(x, mesh: Mesh | None, spec: P):
     if mesh is None:
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compatible ``jax.shard_map``.
-
-    Older jax releases only ship ``jax.experimental.shard_map`` and call the
-    replication check ``check_rep`` instead of ``check_vma``.  The default
-    mirrors jax's own (checking ON); call sites opt out explicitly.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )

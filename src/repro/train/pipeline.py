@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard_map
-
 __all__ = ["pipeline_apply", "num_ticks"]
 
 
@@ -99,7 +97,7 @@ def pipeline_apply(
                      is_leaf=lambda x: hasattr(x, "shape")),
         P(),  # microbatches replicated
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=in_specs,

@@ -62,7 +62,6 @@ from repro.optim.adamw import AdamWConfig, adamw_update
 from repro.parallel.sharding import (
     ShardingPlan,
     constrain,
-    shard_map,
     virtual_experts,
 )
 from repro.train.pipeline import num_ticks
@@ -391,7 +390,7 @@ def make_pp_train_step(
                 wire_l = rest.pop(0) if has_wire else None
                 return per_device(a[0], a[1], perm_l, wire_l)
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 wrapped, mesh=mesh, in_specs=tuple(in_specs),
                 out_specs=out_specs, check_vma=False,
             )

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import AllReduce, CommSpec
 from repro.models import transformer as tfm
 from repro.optim.adamw import AdamWConfig, adamw_update, init_adamw
-from repro.parallel.sharding import ShardingPlan, constrain, shard_map
+from repro.parallel.sharding import ShardingPlan, constrain
 
 __all__ = [
     "make_train_step",
@@ -169,7 +169,7 @@ def _make_runtime_grad_fn(cfg, plan: ShardingPlan, mesh, compress: bool = False)
             res = a[-1] if compress else None
             return body(a[0], a[1], a[2], pm, res)
 
-        f = shard_map(
+        f = jax.shard_map(
             wrapped, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
             check_vma=False,
         )
@@ -519,10 +519,25 @@ def step_shardings(cfg, plan: ShardingPlan, mesh, param_specs):
     return p_sh, opt_sh, batch_sh
 
 
-def init_all(key, cfg, plan, opt_cfg):
-    """(params, specs, opt_state) convenience initializer."""
+def init_all(key, cfg, plan, opt_cfg, mesh=None):
+    """(params, specs, opt_state) convenience initializer.
+
+    With a ``mesh`` the state is created already sharded by its specs (one
+    jitted init with ``out_shardings``): no device ever holds the whole
+    model, which at published widths would not fit one chip."""
     from repro.models.transformer import init_model
 
-    params, specs = init_model(key, cfg, plan)
-    opt_state = init_adamw(params, opt_cfg)
+    if mesh is None:
+        params, specs = init_model(key, cfg, plan)
+        return params, specs, init_adamw(params, opt_cfg)
+    specs = {}
+
+    def init(k):
+        params, s = init_model(k, cfg, plan)
+        specs.update(s)
+        return params, init_adamw(params, opt_cfg)
+
+    jax.eval_shape(init, key)
+    p_sh, opt_sh, _ = step_shardings(cfg, plan, mesh, specs)
+    params, opt_state = jax.jit(init, out_shardings=(p_sh, opt_sh))(key)
     return params, specs, opt_state

@@ -114,7 +114,9 @@ class Trainer:
         self.plan = plan
         self.mesh = mesh
         key = jax.random.PRNGKey(seed)
-        self.params, self.specs, self.opt_state = init_all(key, cfg, plan, opt_cfg)
+        self.params, self.specs, self.opt_state = init_all(
+            key, cfg, plan, opt_cfg, mesh=mesh
+        )
         if tcfg.pp_stages > 1:
             if tcfg.dp_comm != "auto" or tcfg.dp_compress:
                 raise ValueError(
@@ -253,7 +255,12 @@ class Trainer:
         with self._tr.span(
             "train.reconfig", tid=tid, cat="reconfig", step=self.step
         ) as sp:
-            self.params, changed = ap.apply(self.params, plans)
+            # Adam moments move with their expert weights, so re-placement
+            # leaves the optimizer math unchanged.
+            self.params, changed = ap.apply(
+                self.params, plans,
+                states=(self.opt_state["mu"], self.opt_state["nu"]),
+            )
             sp.set(
                 applied=bool(changed),
                 plans=sum(1 for p in plans if p.reconfigure),

@@ -17,7 +17,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import hierarchical_all_to_all, flat_all_to_all, hierarchical_psum
 
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 mesh = _compat_make_mesh((8,), ('model',))
 x = jnp.arange(8 * 8 * 4, dtype=jnp.float32).reshape(8, 8 * 4)  # per-dev [8,4]
 
@@ -26,8 +25,8 @@ def run(group):
         return hierarchical_all_to_all(v.reshape(8, 4), 'model', group).reshape(1, 32)
     def flat(v):
         return flat_all_to_all(v.reshape(8, 4), 'model').reshape(1, 32)
-    h = _compat_shard_map(hier, mesh=mesh, in_specs=P('model'), out_specs=P('model'))(x)
-    f = _compat_shard_map(flat, mesh=mesh, in_specs=P('model'), out_specs=P('model'))(x)
+    h = jax.shard_map(hier, mesh=mesh, in_specs=P('model'), out_specs=P('model'))(x)
+    f = jax.shard_map(flat, mesh=mesh, in_specs=P('model'), out_specs=P('model'))(x)
     np.testing.assert_array_equal(np.asarray(h), np.asarray(f)), group
 
 for g in (1, 2, 4, 8):
@@ -35,12 +34,11 @@ for g in (1, 2, 4, 8):
 
 # hierarchical psum == plain psum over both axes
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 mesh2 = _compat_make_mesh((2, 4), ('data', 'model'))
 y = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
-a = _compat_shard_map(lambda v: hierarchical_psum(v, 'model', 'data', scatter_dim=0),
+a = jax.shard_map(lambda v: hierarchical_psum(v, 'model', 'data', scatter_dim=0),
                   mesh=mesh2, in_specs=P(('data', 'model')), out_specs=P(('data', 'model')))(y)
-b = _compat_shard_map(lambda v: jax.lax.psum(jax.lax.psum(v, 'model'), 'data'),
+b = jax.shard_map(lambda v: jax.lax.psum(jax.lax.psum(v, 'model'), 'data'),
                   mesh=mesh2, in_specs=P(('data', 'model')), out_specs=P(('data', 'model')))(y)
 np.testing.assert_allclose(np.asarray(a), np.asarray(b))
 print('COLLECTIVES_OK')
@@ -57,12 +55,11 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import ring_all_gather
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 mesh = _compat_make_mesh((8,), ('model',))
 x = jnp.arange(8 * 2 * 3, dtype=jnp.float32).reshape(8 * 2, 3)
-ring = _compat_shard_map(lambda v: ring_all_gather(v, 'model'), mesh=mesh,
+ring = jax.shard_map(lambda v: ring_all_gather(v, 'model'), mesh=mesh,
                      in_specs=P('model'), out_specs=P(None), check_vma=False)(x)
-ref = _compat_shard_map(lambda v: jax.lax.all_gather(v, 'model', axis=0, tiled=True),
+ref = jax.shard_map(lambda v: jax.lax.all_gather(v, 'model', axis=0, tiled=True),
                     mesh=mesh, in_specs=P('model'), out_specs=P(None), check_vma=False)(x)
 np.testing.assert_allclose(np.asarray(ring), np.asarray(ref))
 print('RING_OK')
@@ -80,7 +77,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import AllToAll, CommSpec
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 
 PDEV = 8
 mesh = _compat_make_mesh((PDEV,), ('model',))
@@ -90,7 +86,7 @@ perms = [None, tuple(rng.permutation(PDEV).tolist()), tuple(np.roll(np.arange(PD
 
 def run(spec):
     op = AllToAll(spec)
-    f = _compat_shard_map(lambda v: op(v.reshape(PDEV, 4)).reshape(1, PDEV * 4),
+    f = jax.shard_map(lambda v: op(v.reshape(PDEV, 4)).reshape(1, PDEV * 4),
                           mesh=mesh, in_specs=P('model'), out_specs=P('model'))
     return np.asarray(f(x))
 
@@ -119,12 +115,12 @@ from repro.core.commruntime import Permute
 blocks = jnp.arange(PDEV, dtype=jnp.float32).reshape(PDEV, 1)  # device k holds [k]
 p = tuple(rng.permutation(PDEV).tolist())
 op = Permute(CommSpec(axis='model', axis_size=PDEV)).reconfigure(dest_perm=p)
-moved = _compat_shard_map(lambda v: op(v), mesh=mesh,
+moved = jax.shard_map(lambda v: op(v), mesh=mesh,
                           in_specs=P('model'), out_specs=P('model'),
                           check_vma=False)(blocks)
 np.testing.assert_array_equal(np.asarray(moved)[:, 0], np.asarray(p, np.float32))
 # default: +1 ring shift of the blocks (device k receives from k-1)
-ring = _compat_shard_map(lambda v: Permute(CommSpec(axis='model', axis_size=PDEV))(v),
+ring = jax.shard_map(lambda v: Permute(CommSpec(axis='model', axis_size=PDEV))(v),
                          mesh=mesh, in_specs=P('model'), out_specs=P('model'),
                          check_vma=False)(blocks)
 np.testing.assert_array_equal(np.asarray(ring)[:, 0],
@@ -144,7 +140,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import AllToAll, CommSpec
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 
 PDEV, C, D = 8, 6, 10
 mesh = _compat_make_mesh((PDEV,), ('model',))
@@ -159,7 +154,7 @@ for dtype in (jnp.float32, jnp.bfloat16):
             return rx, re
         def unfused(v, m):
             return op(v), op(m[..., None])[..., 0]
-        sm = lambda f: _compat_shard_map(f, mesh=mesh, in_specs=(P('model'), P('model')),
+        sm = lambda f: jax.shard_map(f, mesh=mesh, in_specs=(P('model'), P('model')),
                                          out_specs=(P('model'), P('model')), check_vma=False)
         fx, fe = sm(fused)(x, e)
         ux, ue = sm(unfused)(x, e)
@@ -176,7 +171,7 @@ for dtype in (jnp.float32, jnp.bfloat16):
         return (rx ** 2).sum()
     def loss_unfused(v, m):
         return (op(v) ** 2).sum()
-    smg = lambda f: _compat_shard_map(
+    smg = lambda f: jax.shard_map(
         lambda v, m: jax.grad(f)(v, m), mesh=mesh,
         in_specs=(P('model'), P('model')), out_specs=P('model'), check_vma=False)
     gf = smg(loss_fused)(x32, e)
@@ -197,7 +192,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import AllGather, CommSpec
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 
 # Equivalence of the runtime's ring AllGather lowering vs lax.all_gather
 # across axis sizes, INCLUDING the P=1 degenerate mesh.
@@ -206,10 +200,10 @@ for p in (1, 2, 4, 8):
     x = jnp.arange(p * 2 * 3, dtype=jnp.float32).reshape(p * 2, 3)
     ring_op = AllGather(CommSpec(axis='model', axis_size=p), impl='ring')
     flat_op = AllGather(CommSpec(axis='model', axis_size=p), impl='flat')
-    run = lambda op: _compat_shard_map(lambda v: op(v), mesh=mesh,
+    run = lambda op: jax.shard_map(lambda v: op(v), mesh=mesh,
                                        in_specs=P('model'), out_specs=P(None),
                                        check_vma=False)(x)
-    ref = _compat_shard_map(lambda v: jax.lax.all_gather(v, 'model', axis=0, tiled=True),
+    ref = jax.shard_map(lambda v: jax.lax.all_gather(v, 'model', axis=0, tiled=True),
                             mesh=mesh, in_specs=P('model'), out_specs=P(None),
                             check_vma=False)(x)
     np.testing.assert_array_equal(np.asarray(run(ring_op)), np.asarray(ref)), p

@@ -1,6 +1,8 @@
 """Launch-layer units: trip-aware HLO analyzer, roofline math, sharding
 plan rules, data pipeline determinism."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,3 +114,26 @@ def test_input_specs_no_allocation():
             specs = input_specs(cfg, shape, mesh, plan)
             for leaf in jax.tree.leaves(specs):
                 assert isinstance(leaf, jax.ShapeDtypeStruct), (arch, shape.name)
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_fixed(env_dir, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes to
+    the fixed .jax_cache/ at the checkout root."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(compile_cache.CHECKOUT_CACHE_DIR)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        expect_cfg = before if env_dir else want
+        assert jax.config.jax_compilation_cache_dir == expect_cfg
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(compile_cache.CHECKOUT_CACHE_DIR) == os.path.join(repo, ".jax_cache")

@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models import moe as moe_mod
 from repro.models.config import ModelConfig, MoEConfig
@@ -93,7 +94,6 @@ from repro.models import moe as moe_mod
 from repro.parallel.sharding import make_plan
 
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.launch.mesh import use_mesh as _compat_use_mesh
 mesh = _compat_make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
 plan1 = make_plan(None)
@@ -105,7 +105,7 @@ params, _ = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, plan)
 params1, _ = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, plan1)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
 ref, ref_st = moe_mod.moe_apply(params1, x, cfg, plan1, backend='einsum')
-with _compat_use_mesh(mesh):
+with jax.set_mesh(mesh):
     out, st = jax.jit(lambda p, v: moe_mod.moe_apply(p, v, cfg, plan, mesh=mesh, backend='mixnet'))(params, x)
 assert float(jnp.max(jnp.abs(ref - out))) < 1e-5
 np.testing.assert_allclose(np.asarray(ref_st.expert_load), np.asarray(st.expert_load))
@@ -114,7 +114,7 @@ np.testing.assert_allclose(np.asarray(ref_st.expert_load), np.asarray(st.expert_
 cfg2 = ModelConfig('t2', 'moe', 2, 32, 4, 2, 64, 128, dtype='float32',
                    moe=MoEConfig(num_experts=2, top_k=1, d_ff=48, capacity_factor=8.0, a2a_group=2))
 params2, _ = moe_mod.init_moe(jax.random.PRNGKey(2), cfg2, plan)
-with _compat_use_mesh(mesh):
+with jax.set_mesh(mesh):
     o_m, _ = jax.jit(lambda p, v: moe_mod.moe_apply(p, v, cfg2, plan, mesh=mesh, backend='mixnet'))(params2, x)
     o_e, _ = jax.jit(lambda p, v: moe_mod.moe_apply(p, v, cfg2, plan, mesh=mesh, backend='einsum'))(params2, x)
 assert float(jnp.max(jnp.abs(o_m - o_e))) < 1e-5
@@ -126,7 +126,7 @@ perm = np.array([3,1,4,0,6,2,7,5], dtype=np.int32)
 pp = dict(params)
 pp_moe = {k: (apply_placement(v, perm) if k in ('w_in','w_gate','w_out') else v)
           for k, v in params.items()}
-with _compat_use_mesh(mesh):
+with jax.set_mesh(mesh):
     out_p, _ = jax.jit(lambda p, v: moe_mod.moe_apply(p, v, cfg, plan, mesh=mesh,
                        backend='mixnet', expert_perm=jnp.asarray(perm)))(pp_moe, x)
 assert float(jnp.max(jnp.abs(out_p - ref))) < 1e-5, 'placement permutation changed the math'
@@ -146,7 +146,6 @@ from repro.models.config import ModelConfig, MoEConfig
 from repro.models import moe as moe_mod
 from repro.parallel.sharding import make_plan
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.launch.mesh import use_mesh as _compat_use_mesh
 
 mesh = _compat_make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
@@ -163,7 +162,7 @@ for dtype in ('float32', 'bfloat16'):
             cfg, moe=dataclasses.replace(cfg.moe, a2a_fuse=False))
         params, _ = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, plan)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32)).astype(cfg.dtype)
-        with _compat_use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out_f, st_f = jax.jit(lambda p, v: moe_mod.moe_apply(
                 p, v, cfg, plan, mesh=mesh, backend='mixnet'))(params, x)
             out_u, st_u = jax.jit(lambda p, v: moe_mod.moe_apply(

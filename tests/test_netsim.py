@@ -3,7 +3,8 @@ headline claims (validated numerically in benchmarks; sanity-tested here)."""
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.paper_models import MIXTRAL_8X7B, SIM_MODELS
 from repro.core import cost as costm

@@ -123,7 +123,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import AllToAll, CommSpec
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import shard_map
 
 PDEV = 8
 mesh = make_mesh((PDEV,), ('model',))
@@ -141,7 +140,7 @@ for g, dp, sp in itertools.product((1, 2, 4), perms, perms):
         for s in op.stages():
             y = s(y)
         return y.reshape(1, PDEV * 4)
-    run = lambda f: np.asarray(shard_map(f, mesh=mesh, in_specs=P('model'),
+    run = lambda f: np.asarray(jax.shard_map(f, mesh=mesh, in_specs=P('model'),
                                          out_specs=P('model'))(x))
     np.testing.assert_array_equal(run(staged), run(whole)), (g, dp, sp)
 print('STAGED_A2A_OK')
@@ -160,7 +159,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.commruntime import ReduceScatter, CommSpec, ring_reduce_scatter
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import shard_map
 
 for p in (2, 4, 8):
     mesh = make_mesh((p,), ('model',))
@@ -168,7 +166,7 @@ for p in (2, 4, 8):
     xi = jnp.arange(p * p * 3, dtype=jnp.int32).reshape(p * p, 3)
     ring_op = ReduceScatter(CommSpec(axis='model', axis_size=p), impl='ring')
     flat_op = ReduceScatter(CommSpec(axis='model', axis_size=p), impl='flat')
-    run = lambda op, v: np.asarray(shard_map(
+    run = lambda op, v: np.asarray(jax.shard_map(
         lambda u: op(u), mesh=mesh, in_specs=P('model'), out_specs=P('model'),
         check_vma=False)(v))
     np.testing.assert_array_equal(run(ring_op, xi), run(flat_op, xi)), p
@@ -179,10 +177,10 @@ for p in (2, 4, 8):
     # non-zero scatter_dim: per-device distinct [2, 2p] inputs reduced over
     # the axis and scattered along dim 1
     xt = jax.random.normal(jax.random.PRNGKey(p + 10), (p * 2, 2 * p))
-    a = np.asarray(shard_map(lambda u: ring_reduce_scatter(u, 'model', scatter_dim=1),
+    a = np.asarray(jax.shard_map(lambda u: ring_reduce_scatter(u, 'model', scatter_dim=1),
                              mesh=mesh, in_specs=P('model', None),
                              out_specs=P(None, 'model'), check_vma=False)(xt))
-    b = np.asarray(shard_map(
+    b = np.asarray(jax.shard_map(
         lambda u: jax.lax.psum_scatter(u, 'model', scatter_dimension=1, tiled=True),
         mesh=mesh, in_specs=P('model', None), out_specs=P(None, 'model'),
         check_vma=False)(xt))
@@ -238,7 +236,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.models import moe as moe_mod
 from repro.models.config import ModelConfig, MoEConfig
 from repro.parallel.sharding import make_plan
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 # P sweep over model sizes {2, 4, 8} on 8 forced devices (P=1 runs
 # in-process in the test file); chunks {1, 2, 4} x dropless/capacity,
@@ -256,7 +254,7 @@ for shape, axes in (((4, 2), ('data', 'model')),
                                         dispatch=dispatch))
         params, _ = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, plan)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             base, st0 = jax.jit(lambda p, v: moe_mod.moe_apply(
                 p, v, cfg, plan, mesh=mesh, backend='mixnet'))(params, x)
             for c in (2, 4):
@@ -291,7 +289,7 @@ for dispatch in ('dropless', 'capacity'):
     for wname in ('w_in', 'w_gate', 'w_out'):
         pw[wname] = params[wname][inv_phi]
     wire = jnp.asarray(D, jnp.int32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         outs = {}
         for c in (1, 2, 4):
             cfg_c = dataclasses.replace(
@@ -307,7 +305,7 @@ for dispatch in ('dropless', 'capacity'):
     # physically-resident weights (the analogue of PR 2's decode fix).
     x1 = x[:, :1]
     ref1, _ = moe_mod.moe_apply(params1, x1, cfg, plan1, backend='einsum')
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         od, _ = jax.jit(lambda p, v, w: moe_mod.moe_apply(
             p, v, cfg, plan, mesh=mesh, backend='mixnet', mode='decode',
             wire_perm=w))(pw, x1, wire)
@@ -457,7 +455,7 @@ from repro.models.config import ModelConfig, MoEConfig
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
 from repro.train.train_step import init_all, make_train_step
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)  # fsdp axis = data
@@ -479,7 +477,7 @@ for cfg in cfgs:
     cfg_p = dataclasses.replace(cfg, fsdp_prefetch=True)
     params, specs, opt_state = init_all(jax.random.PRNGKey(0), cfg, plan, opt)
     opt_state2 = jax.tree.map(lambda a: a, opt_state)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         s0 = jax.jit(make_train_step(cfg, plan, opt, mesh=mesh))
         s1 = jax.jit(make_train_step(cfg_p, plan, opt, mesh=mesh))
         p0, o0, m0 = s0(params, opt_state, batch)
@@ -512,7 +510,7 @@ from repro.models.config import ModelConfig, MoEConfig
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
 from repro.train.train_step import init_all, init_ef_residual, make_train_step
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((8,), ('data',))
 plan = make_plan(mesh, fsdp=False)
@@ -528,7 +526,7 @@ res = init_ef_residual(params, plan)
 data = SyntheticLM(cfg.vocab_size, 16, 8, seed=0)
 b = next(data)
 batch = {'tokens': jnp.asarray(b.tokens), 'labels': jnp.asarray(b.labels)}
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     base = jax.jit(make_train_step(cfg, plan, opt, mesh=mesh, dp_comm='runtime'))
     comp = jax.jit(make_train_step(cfg, plan, opt, mesh=mesh, dp_comm='runtime',
                                    dp_compress=True))
@@ -546,7 +544,7 @@ assert 0.0 < rmax < 1.0, rmax
 # error feedback keeps the long-run mean unbiased: iterate the compressed
 # step on a FIXED batch and compare the parameter drift direction
 p1, o1, r1 = params, jax.tree.map(lambda a: a, opt_state), res
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     for _ in range(3):
         p1, o1, m1, r1 = comp(p1, o1, batch, None, None, r1)
 assert np.isfinite(float(m1['loss']))
@@ -602,7 +600,7 @@ from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
 from repro.train.train_step import loss_fn
 from repro.train.trainer import Trainer, TrainerConfig
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
@@ -650,7 +648,7 @@ batch = {'tokens': jnp.asarray(b.tokens), 'labels': jnp.asarray(b.labels)}
 
 def loss_of(t):
     wire = jnp.asarray(t.wire_perm, jnp.int32) if t.wire_perm is not None else None
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         l, _ = jax.jit(lambda p, bt, pm, wr: loss_fn(
             p, bt, cfg, plan, mesh, pm, wr))(
             t.params, batch, jnp.asarray(t.expert_perm), wire)
@@ -671,7 +669,7 @@ np.testing.assert_allclose(loss_of(tr), loss_of(ref), rtol=1e-5)
 # and training still runs through the installed wire perms
 tr2 = make()
 tr2._apply_layer_plans([LayerPlan(0, True, perm=block_perm.copy())])
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     log = tr2.train(iter(SyntheticLM(cfg.vocab_size, 16, 4, seed=0)))
 assert np.isfinite([float(m['loss']) for m in log]).all()
 print('WIRE_TRAINER_OK')

@@ -299,7 +299,6 @@ from repro.parallel.sharding import make_plan
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.workload import WorkloadGenerator
 from repro.launch.mesh import make_mesh as _mm
-from repro.launch.mesh import use_mesh as _um
 
 P = %(P)d
 mesh = _mm((P,), ("model",))
@@ -322,7 +321,7 @@ for dispatch in ("dropless", "capacity"):
                            reconfig_min_gain=0.0, num_devices=P)
         eng = ServeEngine(jax.tree.map(lambda a: a, params), cfg, plan, scfg,
                           mesh=mesh)
-        with _um(mesh):
+        with jax.set_mesh(mesh):
             if reconfig:
                 # Force one expert-weight permutation so the paged decode
                 # path provably runs under a moved placement (the control

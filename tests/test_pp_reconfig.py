@@ -11,7 +11,7 @@ _COMMON = """
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig, MoEConfig
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
@@ -49,7 +49,7 @@ def make_trainer(pp, tmp=None, **tkw):
 
 def run_chunk(tr, mesh, data, upto):
     tr.tcfg = dataclasses.replace(tr.tcfg, total_steps=upto)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         tr.train(data)
     return [float(m['loss']) for m in tr.metrics_log]
 

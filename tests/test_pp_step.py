@@ -28,7 +28,7 @@ from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
 from repro.train.train_step import init_all, make_train_step
 from repro.train.pp_step import make_pp_train_step
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 # heads deliberately NOT divisible by the model axis (attention inside a PP
 # stage computes replicated on the gathered sequence; keep the non-PP
@@ -54,7 +54,7 @@ def run_pp(s, p, m, perm=None, wire=None, seed=0, jit=False):
     mesh = make_mesh((s, p), ('stage', 'model'))
     plan = make_plan(mesh, fsdp=False)
     params, opt_state = fresh_state()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_pp_train_step(
             CFG, plan, OPT, mesh, pp_stages=s, microbatches=m)
         if jit:
@@ -67,7 +67,7 @@ def run_ref(p, m=1, perm=None, wire=None, seed=0, jit=False):
     mesh = make_mesh((p,), ('model',))
     plan = make_plan(mesh)
     params, opt_state = fresh_state()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(CFG, plan, OPT, mesh=mesh, microbatches=m)
         if jit:
             step = jax.jit(step)

@@ -208,7 +208,7 @@ from repro.models import moe as moe_mod
 from repro.parallel.sharding import make_plan
 from repro.core.placement import apply_placement
 
-from repro.launch.mesh import make_mesh as _mk, use_mesh as _um
+from repro.launch.mesh import make_mesh as _mk
 mesh = _mk((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
 plan1 = make_plan(None)
@@ -221,7 +221,7 @@ for dispatch in ('dropless', 'capacity'):
                                     capacity_factor=8.0, a2a_group=2,
                                     dispatch=dispatch))
     params, _ = moe_mod.init_moe(jax.random.PRNGKey(2), cfg, plan)
-    with _um(mesh):
+    with jax.set_mesh(mesh):
         o_m, st_m = jax.jit(lambda p, v: moe_mod.moe_apply(
             p, v, cfg, plan, mesh=mesh, backend='mixnet'))(params, x)
         o_e, st_e = jax.jit(lambda p, v: moe_mod.moe_apply(
@@ -234,7 +234,7 @@ for dispatch in ('dropless', 'capacity'):
     perm = np.array([2, 0, 3, 1], dtype=np.int32)
     pp = {k: (apply_placement(v, perm) if k in ('w_in', 'w_gate', 'w_out') else v)
           for k, v in params.items()}
-    with _um(mesh):
+    with jax.set_mesh(mesh):
         o_p, _ = jax.jit(lambda p, v: moe_mod.moe_apply(
             p, v, cfg, plan, mesh=mesh, backend='mixnet',
             expert_perm=jnp.asarray(perm)))(pp, x)

@@ -140,7 +140,6 @@ from repro.parallel.sharding import make_plan
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.workload import WorkloadGenerator
 from repro.launch.mesh import make_mesh as _mm
-from repro.launch.mesh import use_mesh as _um
 
 P = %(P)d
 mesh = _mm((P,), ("model",))
@@ -164,7 +163,7 @@ for dispatch in ("dropless", "capacity"):
                            reconfig_min_gain=0.0, num_devices=P)
         eng = ServeEngine(jax.tree.map(lambda a: a, params), cfg, plan, scfg,
                           mesh=mesh)
-        with _um(mesh):
+        with jax.set_mesh(mesh):
             if reconfig:
                 # Force one whole-device-block plan: realized as a WIRE
                 # re-address on the decode a2a (weights never move).

@@ -286,7 +286,6 @@ from repro.parallel.sharding import make_plan
 from repro.serve.batching import Request
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.launch.mesh import make_mesh as _mm
-from repro.launch.mesh import use_mesh as _um
 
 P = %(P)d
 mesh = _mm((P,), ("model",))
@@ -311,7 +310,7 @@ for dispatch, shared in (("dropless", 1), ("capacity", 0)):
                            reconfig_min_gain=0.0, num_devices=P)
         eng = ServeEngine(jax.tree.map(lambda a: a, params), cfg, plan, scfg,
                           mesh=mesh)
-        with _um(mesh):
+        with jax.set_mesh(mesh):
             if reconfig:
                 perm = np.arange(8)
                 perm[[0, 1]] = perm[[1, 0]]

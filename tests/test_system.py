@@ -60,7 +60,6 @@ from repro.parallel.sharding import make_plan
 from repro.train.train_step import init_all, make_train_step, step_shardings
 
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.launch.mesh import use_mesh as _compat_use_mesh
 mesh = _compat_make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
 cfg = ModelConfig('md', 'moe', 2, 32, 4, 2, 0, 64, dtype='float32', remat='none',
@@ -74,7 +73,7 @@ opt_state = jax.device_put(opt_state, opt_sh)
 tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
 batch = {'tokens': jax.device_put(tokens, b_sh['tokens']),
          'labels': jax.device_put(jnp.roll(tokens, -1, 1), b_sh['labels'])}
-with _compat_use_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(make_train_step(cfg, plan, opt_cfg, mesh=mesh))
     params2, opt2, metrics = step(params, opt_state, batch)
 loss_md = float(metrics['loss'])
@@ -108,7 +107,6 @@ from repro.train import checkpoint as ckpt
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.launch.mesh import use_mesh as _compat_use_mesh
 mesh_a = _compat_make_mesh((8,), ('data',))
 mesh_b = _compat_make_mesh((2, 4), ('data', 'model'))
 tree = {'w': jax.device_put(jnp.arange(64.0).reshape(8, 8),
@@ -133,13 +131,12 @@ from repro.models import transformer as tfm
 from repro.parallel.sharding import make_plan
 
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.launch.mesh import use_mesh as _compat_use_mesh
 mesh = _compat_make_mesh((2, 4), ('data', 'model'))
 plan = make_plan(mesh)
 cfg = ModelConfig('sp', 'dense', 2, 32, 8, 4, 64, 128, dtype='float32', remat='none')
 params, _ = tfm.init_model(jax.random.PRNGKey(0), cfg, plan)
 tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
-with _compat_use_mesh(mesh):
+with jax.set_mesh(mesh):
     base, _, _ = jax.jit(lambda p, t: tfm.model_apply(p, {'tokens': t}, cfg, plan, mesh=mesh, mode='train'))(params, tokens)
     cfg_sp = dataclasses.replace(cfg, sp_shardmap=True)
     sp, _, _ = jax.jit(lambda p, t: tfm.model_apply(p, {'tokens': t}, cfg_sp, plan, mesh=mesh, mode='train'))(params, tokens)

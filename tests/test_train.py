@@ -118,12 +118,11 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compress import compressed_psum
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.parallel.sharding import shard_map as _compat_shard_map
 mesh = _compat_make_mesh((8,), ('data',))
 x = jax.random.normal(jax.random.PRNGKey(0), (8 * 4, 16))
-exact = _compat_shard_map(lambda v: jax.lax.psum(v, 'data'), mesh=mesh,
+exact = jax.shard_map(lambda v: jax.lax.psum(v, 'data'), mesh=mesh,
                       in_specs=P('data'), out_specs=P('data'))(x)
-approx = _compat_shard_map(lambda v: compressed_psum(v, 'data'), mesh=mesh,
+approx = jax.shard_map(lambda v: compressed_psum(v, 'data'), mesh=mesh,
                        in_specs=P('data'), out_specs=P('data'))(x)
 rel = float(jnp.max(jnp.abs(exact - approx)) / (jnp.max(jnp.abs(exact)) + 1e-9))
 assert rel < 0.05, rel
@@ -144,7 +143,6 @@ from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import make_plan
 from repro.train.train_step import init_all, make_train_step
 from repro.launch.mesh import make_mesh as _compat_make_mesh
-from repro.launch.mesh import use_mesh as _compat_use_mesh
 
 mesh = _compat_make_mesh((8,), ('data',))
 # fsdp=False: the runtime mode replicates params inside its shard_map and
@@ -165,7 +163,7 @@ opt_state2 = jax.tree.map(lambda a: a, opt_state)
 data = SyntheticLM(cfg.vocab_size, 16, 8, seed=0)
 b = next(data)
 batch = {'tokens': jnp.asarray(b.tokens), 'labels': jnp.asarray(b.labels)}
-with _compat_use_mesh(mesh):
+with jax.set_mesh(mesh):
     auto_step = jax.jit(make_train_step(cfg, plan, opt, mesh=mesh))
     rt_step = jax.jit(make_train_step(cfg, plan, opt, mesh=mesh, dp_comm='runtime'))
     pa, oa, ma = auto_step(params, opt_state, batch)
@@ -200,6 +198,51 @@ def test_runtime_dp_grad_reduce_matches_auto(multidevice):
     per-shard gradients reproduces the XLA-auto pjit step."""
     out = multidevice(RUNTIME_DP, devices=8, timeout=900)
     assert "RUNTIME_DP_OK" in out
+
+
+REPLACEMENT_PARITY = """
+import jax, numpy as np
+from repro.data.pipeline import SyntheticLM
+from repro.launch.mesh import make_mesh
+from repro.models.config import ModelConfig, MoEConfig
+from repro.optim.adamw import AdamWConfig
+from repro.parallel.sharding import make_plan
+from repro.train.trainer import Trainer, TrainerConfig
+
+cfg = ModelConfig('tiny-moe', 'moe', 2, 32, 4, 2, 0, 64, dtype='float32',
+                  remat='none',
+                  moe=MoEConfig(num_experts=8, top_k=2, d_ff=32, backend='mixnet'))
+mesh = make_mesh((1, 4), ('data', 'model'))
+plan = make_plan(mesh)
+
+def run(every):
+    tcfg = TrainerConfig(total_steps=6, reconfig_every=every, reconfig_min_gain=0.0)
+    tr = Trainer(cfg, AdamWConfig(lr=1e-2, warmup_steps=1), tcfg, plan,
+                 mesh=mesh, seed=0)
+    with jax.set_mesh(mesh):
+        log = tr.train(iter(SyntheticLM(cfg.vocab_size, 16, 4, seed=0)))
+    return tr, [float(m['loss']) for m in log]
+
+tr, moved = run(2)
+_, fixed = run(0)
+assert tr.reconfig_count > 0
+# weights and their Adam moments move together: training is unchanged
+np.testing.assert_allclose(moved, fixed, rtol=1e-6)
+# re-placed expert tensors stay expert-sharded, 2 of 8 experts per device
+for tree in (tr.params, tr.opt_state['mu'], tr.opt_state['nu']):
+    for name in ('w_in', 'w_gate', 'w_out'):
+        leaf = tree['blocks']['0_global']['moe'][name]
+        per_dev = {s.device.id: s.data.shape[1] for s in leaf.addressable_shards}
+        assert per_dev == {0: 2, 1: 2, 2: 2, 3: 2}, (name, per_dev)
+print('REPLACEMENT_PARITY_OK')
+"""
+
+
+def test_trainer_replacement_keeps_losses_and_expert_sharding(multidevice):
+    """Live expert re-placement on a 4-device model axis: losses equal the
+    run without re-placement, and the permuted experts stay sharded."""
+    out = multidevice(REPLACEMENT_PARITY, devices=4, timeout=900)
+    assert "REPLACEMENT_PARITY_OK" in out
 
 
 def test_trainer_per_layer_reconfig_distinct_perms():

@@ -145,8 +145,9 @@ def _decode_attention(
     if write_mask is not None:
         # Dead rows scatter out of bounds and are dropped.
         idx = jnp.where(write_mask[:, None], idx, s)
-    ck = ck.at[bi, idx].set(k_new.astype(ck.dtype), mode="drop")
-    cv = cv.at[bi, idx].set(v_new.astype(cv.dtype), mode="drop")
+    with jax.named_scope("kv.write"):
+        ck = ck.at[bi, idx].set(k_new.astype(ck.dtype), mode="drop")
+        cv = cv.at[bi, idx].set(v_new.astype(cv.dtype), mode="drop")
     out = _attend_cache_view(q, ck, cv, pos_c, window=window, softcap=softcap)
     return out, {"k": ck, "v": cv}
 
@@ -226,8 +227,9 @@ def _paged_decode_attention(
     if write_mask is not None:
         pid = jnp.where(write_mask[:, None], pid, -1)
     pid = jnp.where(pid >= 0, pid, n_pages)  # unallocated/dead -> dropped
-    ck = ck.at[pid, off].set(k_new.astype(ck.dtype), mode="drop")
-    cv = cv.at[pid, off].set(v_new.astype(cv.dtype), mode="drop")
+    with jax.named_scope("kv.write"):
+        ck = ck.at[pid, off].set(k_new.astype(ck.dtype), mode="drop")
+        cv = cv.at[pid, off].set(v_new.astype(cv.dtype), mode="drop")
     new_cache = {"k": ck, "v": cv}
     if ops.on_tpu():
         out = ops.paged_flash_decode(

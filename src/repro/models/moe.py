@@ -170,14 +170,15 @@ def _moe_einsum(params, x, cfg, plan: ShardingPlan, mesh=None, expert_perm=None)
     # an all-gather, combine a reduce-scatter — the static-fabric baseline
     # the mixnet backend's true hierarchical a2a improves on (§Perf).
     xg = constrain(xg, mesh, P(gspec, None, None))
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32), params["router"])
-    info = routing.compute_routing(
-        logits.reshape(-1, e.num_experts),
-        top_k=e.top_k,
-        num_virtual=ev,
-        replication=r,
-        expert_perm=expert_perm,
-    )
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32), params["router"])
+        info = routing.compute_routing(
+            logits.reshape(-1, e.num_experts),
+            top_k=e.top_k,
+            num_virtual=ev,
+            replication=r,
+            expert_perm=expert_perm,
+        )
     vdest = info.vdest.reshape(b * g, t * sc)
     wfull = info.wfull.reshape(b * g, t, sc)
 
@@ -193,25 +194,28 @@ def _moe_einsum(params, x, cfg, plan: ShardingPlan, mesh=None, expert_perm=None)
         cap = t
     else:
         cap = routing.capacity(t, e.top_k, e.num_experts, e.capacity_factor)
-    rank, _ = jax.vmap(lambda dv: routing.bucket_ranks(dv, ev))(vdest)
-    vdest = vdest.reshape(b * g, t, sc)
-    rank = rank.reshape(b * g, t, sc)
-    keep = rank < cap
-    dispatch, combine = routing.dense_dispatch_masks(
-        vdest, rank, keep, wfull, ev, cap
-    )
-    dropped = 1.0 - keep.sum() / (b * g * t * sc)
-
-    xe = jnp.einsum("gtec,gtd->gecd", dispatch.astype(x.dtype), xg)  # [G,Ev,C,D]
     ex_ax = plan.dim_axis(ev)
-    xe = constrain(xe, mesh, P(gspec, ex_ax, None, None))
-    ye = _expert_ffn(xe, params["w_in"], params["w_gate"], params["w_out"], cfg.act)
-    ye = constrain(ye, mesh, P(gspec, ex_ax, None, None))
-    out = jnp.einsum("gtec,gecd->gtd", combine.astype(x.dtype), ye)
-    out = out.reshape(b, s, d)
+    with jax.named_scope("moe.dispatch"):
+        rank, _ = jax.vmap(lambda dv: routing.bucket_ranks(dv, ev))(vdest)
+        vdest = vdest.reshape(b * g, t, sc)
+        rank = rank.reshape(b * g, t, sc)
+        keep = rank < cap
+        dispatch, combine = routing.dense_dispatch_masks(
+            vdest, rank, keep, wfull, ev, cap
+        )
+        dropped = 1.0 - keep.sum() / (b * g * t * sc)
+        xe = jnp.einsum("gtec,gtd->gecd", dispatch.astype(x.dtype), xg)  # [G,Ev,C,D]
+        xe = constrain(xe, mesh, P(gspec, ex_ax, None, None))
+    with jax.named_scope("moe.experts"):
+        ye = _expert_ffn(xe, params["w_in"], params["w_gate"], params["w_out"], cfg.act)
+        ye = constrain(ye, mesh, P(gspec, ex_ax, None, None))
+    with jax.named_scope("moe.combine"):
+        out = jnp.einsum("gtec,gecd->gtd", combine.astype(x.dtype), ye)
+        out = out.reshape(b, s, d)
 
-    balance, z = router_losses(logits, info.idx, e.num_experts)
-    load = routing.expert_load(info.idx, e.num_experts)
+    with jax.named_scope("moe.route"):
+        balance, z = router_losses(logits, info.idx, e.num_experts)
+        load = routing.expert_load(info.idx, e.num_experts)
     stats = MoEStats(load, balance, z, dropped.astype(jnp.float32))
     return out, stats
 
@@ -272,21 +276,24 @@ def _moe_mixnet_local(
         gate_weights = gate_weights.reshape(tl)
     act = _actfn(cfg.act)
 
-    logits = xt.astype(jnp.float32) @ router
-    info = routing.compute_routing(
-        logits, top_k=e.top_k, num_virtual=ev, replication=r,
-        expert_perm=expert_perm,
-    )
-    flat_dev = (info.vdest // ev_local).reshape(n)
-    local_e = (info.vdest % ev_local).reshape(n)
+    with jax.named_scope("moe.route"):
+        logits = xt.astype(jnp.float32) @ router
+        info = routing.compute_routing(
+            logits, top_k=e.top_k, num_virtual=ev, replication=r,
+            expert_perm=expert_perm,
+        )
     wire_dest, wire_src = _wire_perms(wire_perm, p_axis)
 
-    # Stage-1 keep decisions are GLOBAL (chunk-invariant): dropless keeps
-    # everything; capacity mode keeps global rank < the serial capacity, so
-    # chunking never changes which tokens survive the send stage.
-    rank1, _ = routing.bucket_ranks(flat_dev, p_axis)
-    cp = n if dropless else routing.capacity(tl, sc, p_axis, e.capacity_factor)
-    keep1 = None if dropless else rank1 < cp
+    with jax.named_scope("moe.dispatch"):
+        flat_dev = (info.vdest // ev_local).reshape(n)
+        local_e = (info.vdest % ev_local).reshape(n)
+        # Stage-1 keep decisions are GLOBAL (chunk-invariant): dropless
+        # keeps everything; capacity mode keeps global rank < the serial
+        # capacity, so chunking never changes which tokens survive the send
+        # stage.
+        rank1, _ = routing.bucket_ranks(flat_dev, p_axis)
+        cp = n if dropless else routing.capacity(tl, sc, p_axis, e.capacity_factor)
+        keep1 = None if dropless else rank1 < cp
 
     # One CommRuntime op serves the whole layer: the dispatch trip moves the
     # token payload and its expert-id metadata as ONE packed wire transfer
@@ -298,50 +305,56 @@ def _moe_mixnet_local(
     def expert_ffn_block(rx, re):
         """Received rows -> per-expert pack -> grouped GEMM -> unpacked rows.
         Returns (back rows aligned with the receive layout, kept count)."""
-        valid = re >= 0
-        rank2, counts2 = routing.bucket_ranks(re, ev_local, valid=valid)
-        if dropless or chunks > 1:
-            # Block layout: every valid received row is placed (the chunked
-            # pipeline uses it for capacity mode too — static shapes, and
-            # stage-2 never drops below the stage-1 capacity bound).
-            plan2 = routing.dropless_plan(
-                re, rank2, counts2, valid, ev_local, e.dispatch_block
-            )
-            packed = ops.moe_dispatch(rx, plan2.src).reshape(-1, e.dispatch_block, d)
-            be = plan2.block_experts
+        blocks = dropless or chunks > 1
+        with jax.named_scope("moe.dispatch"):
+            valid = re >= 0
+            rank2, counts2 = routing.bucket_ranks(re, ev_local, valid=valid)
+            if blocks:
+                # Block layout: every valid received row is placed (the
+                # chunked pipeline uses it for capacity mode too — static
+                # shapes, and stage-2 never drops below the stage-1
+                # capacity bound).
+                plan2 = routing.dropless_plan(
+                    re, rank2, counts2, valid, ev_local, e.dispatch_block
+                )
+                packed = ops.moe_dispatch(rx, plan2.src).reshape(
+                    -1, e.dispatch_block, d
+                )
+            else:
+                c2 = routing.capacity(re.shape[0], 1, ev_local, e.capacity_factor)
+                plan2 = routing.capacity_plan(re, rank2, valid, ev_local, c2)
+                packed = ops.moe_dispatch(rx, plan2.src).reshape(ev_local, c2, d)
+        with jax.named_scope("moe.experts"):
+            be = plan2.block_experts if blocks else None
             h = ops.grouped_matmul(packed, w_in, block_experts=be)
             gt = ops.grouped_matmul(packed, w_gate, block_experts=be)
             ye = ops.grouped_matmul(act(gt) * h, w_out, block_experts=be)
-        else:
-            c2 = routing.capacity(re.shape[0], 1, ev_local, e.capacity_factor)
-            plan2 = routing.capacity_plan(re, rank2, valid, ev_local, c2)
-            packed = ops.moe_dispatch(rx, plan2.src).reshape(ev_local, c2, d)
-            h = ops.grouped_matmul(packed, w_in)
-            gt = ops.grouped_matmul(packed, w_gate)
-            ye = ops.grouped_matmul(act(gt) * h, w_out)
-        back = ops.moe_dispatch(ye.reshape(plan2.num_rows, d), plan2.slot)
+        with jax.named_scope("moe.combine"):
+            back = ops.moe_dispatch(ye.reshape(plan2.num_rows, d), plan2.slot)
         return back, plan2.kept
 
     if chunks == 1:
         # --- serial path: one send buffer, one a2a pair ---------------------
-        plan1 = routing.capacity_plan(flat_dev, rank1, keep1, p_axis, cp)
-        src_tok = jnp.where(plan1.src >= 0, plan1.src // sc, -1)
-        send_x = ops.moe_dispatch(xt, src_tok).reshape(p_axis, cp, d)
-        send_e = jnp.where(
-            plan1.src >= 0, local_e[jnp.clip(plan1.src, 0, n - 1)], -1
-        ).reshape(p_axis, cp).astype(jnp.int32)
-        if e.a2a_fuse:
-            recv_x, recv_e = a2a.fused(send_x, send_e, dest_perm=wire_dest)
-        else:
-            recv_x = a2a(send_x, dest_perm=wire_dest)
-            recv_e = a2a(send_e[..., None], dest_perm=wire_dest)[..., 0]
+        with jax.named_scope("moe.dispatch"):
+            plan1 = routing.capacity_plan(flat_dev, rank1, keep1, p_axis, cp)
+            src_tok = jnp.where(plan1.src >= 0, plan1.src // sc, -1)
+            send_x = ops.moe_dispatch(xt, src_tok).reshape(p_axis, cp, d)
+            send_e = jnp.where(
+                plan1.src >= 0, local_e[jnp.clip(plan1.src, 0, n - 1)], -1
+            ).reshape(p_axis, cp).astype(jnp.int32)
+            if e.a2a_fuse:
+                recv_x, recv_e = a2a.fused(send_x, send_e, dest_perm=wire_dest)
+            else:
+                recv_x = a2a(send_x, dest_perm=wire_dest)
+                recv_e = a2a(send_e[..., None], dest_perm=wire_dest)[..., 0]
         back, kept = expert_ffn_block(
             recv_x.reshape(p_axis * cp, d), recv_e.reshape(p_axis * cp)
         )
-        ret = a2a(back.reshape(p_axis, cp, d), src_perm=wire_src)
-        out = ops.moe_combine(
-            ret.reshape(p_axis * cp, d), plan1.slot.reshape(tl, sc), info.wfull
-        )
+        with jax.named_scope("moe.combine"):
+            ret = a2a(back.reshape(p_axis, cp, d), src_perm=wire_src)
+            out = ops.moe_combine(
+                ret.reshape(p_axis * cp, d), plan1.slot.reshape(tl, sc), info.wfull
+            )
     else:
         # --- chunked software pipeline (repro.core.overlap) -----------------
         out, kept = _mixnet_chunked(
@@ -351,27 +364,29 @@ def _moe_mixnet_local(
         )
     out = out.reshape(bl, sl, d).astype(xl.dtype)
 
-    balance, z = router_losses(logits, info.idx, e.num_experts)
-    load = routing.expert_load(info.idx, e.num_experts, weights=gate_weights)
-    # Drop telemetry folds BOTH stages: `kept` counts received rows that
-    # won an expert slot, i.e. choices that survived the send-buffer stage
-    # AND the pack stage (stage-1 drops never arrive).  psum'ing kept and
-    # offered over the mesh yields the global realized loss the control
-    # plane acts on (exactly 0 in dropless mode).
-    kept = kept.astype(jnp.float32)
-    offered = jnp.asarray(float(n), jnp.float32)
-    # Reduce telemetry over every mesh axis so replicated out_specs hold.
-    # Counts SUM over axes that shard the token dim and MEAN over axes the
-    # tokens ride replicated (the sparse decode path: every device sees the
-    # whole live batch — a psum there would overcount the load P-fold).
-    for ax in axis_names:
-        red = jax.lax.psum if ax in token_axes else jax.lax.pmean
-        load = red(load, ax)
-        balance = jax.lax.pmean(balance, ax)
-        z = jax.lax.pmean(z, ax)
-        kept = red(kept, ax)
-        offered = red(offered, ax)
-    drop = 1.0 - kept / offered
+    with jax.named_scope("moe.route"):
+        balance, z = router_losses(logits, info.idx, e.num_experts)
+        load = routing.expert_load(info.idx, e.num_experts, weights=gate_weights)
+        # Drop telemetry folds BOTH stages: `kept` counts received rows that
+        # won an expert slot, i.e. choices that survived the send-buffer
+        # stage AND the pack stage (stage-1 drops never arrive).  psum'ing
+        # kept and offered over the mesh yields the global realized loss the
+        # control plane acts on (exactly 0 in dropless mode).
+        kept = kept.astype(jnp.float32)
+        offered = jnp.asarray(float(n), jnp.float32)
+        # Reduce telemetry over every mesh axis so replicated out_specs hold.
+        # Counts SUM over axes that shard the token dim and MEAN over axes
+        # the tokens ride replicated (the sparse decode path: every device
+        # sees the whole live batch — a psum there would overcount the load
+        # P-fold).
+        for ax in axis_names:
+            red = jax.lax.psum if ax in token_axes else jax.lax.pmean
+            load = red(load, ax)
+            balance = jax.lax.pmean(balance, ax)
+            z = jax.lax.pmean(z, ax)
+            kept = red(kept, ax)
+            offered = red(offered, ax)
+        drop = 1.0 - kept / offered
     return out, load, balance, z, drop
 
 
@@ -399,6 +414,7 @@ def _mixnet_chunked(
     ret_stages = a2a.stages()
     fused = fuse and jnp.dtype(xt.dtype).itemsize in (2, 4)
 
+    @jax.named_scope("moe.dispatch")
     def s_build(_, k):
         lo = k * nc
         dest_c = jax.lax.slice_in_dim(flat_dev, lo, lo + nc)
@@ -419,6 +435,7 @@ def _mixnet_chunked(
             st["e"] = disp_stages[0](send_e[..., None], dest_perm=wire_dest)
         return st
 
+    @jax.named_scope("moe.dispatch")
     def s_disp2(st, _):
         st = dict(st)
         st["x"] = disp_stages[1](st["x"])
@@ -427,25 +444,29 @@ def _mixnet_chunked(
         return st
 
     def s_ffn(st, _):
-        if fused:
-            recv_x, recv_e = fuse_unpack(st["x"], d)
-        else:
-            recv_x, recv_e = st["x"], st["e"][..., 0]
+        with jax.named_scope("moe.dispatch"):
+            if fused:
+                recv_x, recv_e = fuse_unpack(st["x"], d)
+            else:
+                recv_x, recv_e = st["x"], st["e"][..., 0]
         back, kept_c = expert_ffn_block(
             recv_x.reshape(p_axis * cp_c, d), recv_e.reshape(p_axis * cp_c)
         )
-        back = back.reshape(p_axis, cp_c, d)
-        if len(ret_stages) == 1:
-            back = ret_stages[0](back, src_perm=wire_src)
-        else:
-            back = ret_stages[0](back)
+        with jax.named_scope("moe.combine"):
+            back = back.reshape(p_axis, cp_c, d)
+            if len(ret_stages) == 1:
+                back = ret_stages[0](back, src_perm=wire_src)
+            else:
+                back = ret_stages[0](back)
         return {"plan1": st["plan1"], "back": back, "kept": kept_c}
 
+    @jax.named_scope("moe.combine")
     def s_ret2(st, _):
         st = dict(st)
         st["back"] = ret_stages[1](st["back"], src_perm=wire_src)
         return st
 
+    @jax.named_scope("moe.combine")
     def s_combine(st, k):
         wf_c = jax.lax.slice_in_dim(info.wfull, k * tc, (k + 1) * tc)
         out_c = ops.moe_combine(
@@ -593,24 +614,28 @@ def _moe_dense_decode(
     b, s, d = x.shape
     ev, r = virtual_experts(e.num_experts, plan.model_size)
     xt = x.reshape(b * s, d)
-    logits = xt.astype(jnp.float32) @ params["router"]
-    info = routing.compute_routing(
-        logits, top_k=e.top_k, num_virtual=ev, replication=r,
-        expert_perm=expert_perm,
-    )
+    with jax.named_scope("moe.route"):
+        logits = xt.astype(jnp.float32) @ params["router"]
+        info = routing.compute_routing(
+            logits, top_k=e.top_k, num_virtual=ev, replication=r,
+            expert_perm=expert_perm,
+        )
 
     ex_ax = plan.dim_axis(ev)
-    h = jnp.einsum("td,edf->tef", xt, params["w_in"])
-    g = jnp.einsum("td,edf->tef", xt, params["w_gate"])
-    h = _actfn(cfg.act)(g) * h
-    h = constrain(h, mesh, P(None, ex_ax, None))
-    y = jnp.einsum("tef,efd->ted", h, params["w_out"])
-    ysel = jnp.take_along_axis(y, info.vdest[:, :, None], axis=1)  # [T, S, D]
-    out = jnp.einsum("ts,tsd->td", info.wfull.astype(y.dtype), ysel)
-    out = out.reshape(b, s, d)
+    with jax.named_scope("moe.experts"):
+        h = jnp.einsum("td,edf->tef", xt, params["w_in"])
+        g = jnp.einsum("td,edf->tef", xt, params["w_gate"])
+        h = _actfn(cfg.act)(g) * h
+        h = constrain(h, mesh, P(None, ex_ax, None))
+        y = jnp.einsum("tef,efd->ted", h, params["w_out"])
+    with jax.named_scope("moe.combine"):
+        ysel = jnp.take_along_axis(y, info.vdest[:, :, None], axis=1)  # [T, S, D]
+        out = jnp.einsum("ts,tsd->td", info.wfull.astype(y.dtype), ysel)
+        out = out.reshape(b, s, d)
 
-    balance, z = router_losses(logits, info.idx, e.num_experts)
-    load = routing.expert_load(info.idx, e.num_experts, weights=gate_weights)
+    with jax.named_scope("moe.route"):
+        balance, z = router_losses(logits, info.idx, e.num_experts)
+        load = routing.expert_load(info.idx, e.num_experts, weights=gate_weights)
     return out, MoEStats(load, balance, z, jnp.zeros((), jnp.float32))
 
 
@@ -695,10 +720,11 @@ def moe_apply(
     else:
         raise ValueError(f"unknown MoE backend {backend!r}")
     if "shared" in params:
-        sh = params["shared"]
-        h = x @ sh["w_in"]
-        g = jax.nn.silu(x @ sh["w_gate"])
-        out = out + (g * h) @ sh["w_out"]
+        with jax.named_scope("moe.experts"):
+            sh = params["shared"]
+            h = x @ sh["w_in"]
+            g = jax.nn.silu(x @ sh["w_gate"])
+            out = out + (g * h) @ sh["w_out"]
     return out, stats
 
 

@@ -251,18 +251,19 @@ def _apply_block(
         attn_cache = (
             {k: v for k, v in cache.items() if k not in ("xk", "xv")} if cache else None
         )
-        if cfg.mla is not None:
-            y, ac = L.mla_attention_apply(
-                p["attn"], h, cfg, mode=mode, cache=attn_cache, t=t,
-                positions=positions, plan=plan, mesh=mesh,
-                write_mask=write_mask,
-            )
-        else:
-            y, ac = L.attention_apply(
-                p["attn"], h, cfg, kind=kind, mode=mode, cache=attn_cache, t=t,
-                positions=positions, plan=plan, mesh=mesh,
-                write_mask=write_mask, page_table=page_table,
-            )
+        with jax.named_scope("attn"):
+            if cfg.mla is not None:
+                y, ac = L.mla_attention_apply(
+                    p["attn"], h, cfg, mode=mode, cache=attn_cache, t=t,
+                    positions=positions, plan=plan, mesh=mesh,
+                    write_mask=write_mask,
+                )
+            else:
+                y, ac = L.attention_apply(
+                    p["attn"], h, cfg, kind=kind, mode=mode, cache=attn_cache,
+                    t=t, positions=positions, plan=plan, mesh=mesh,
+                    write_mask=write_mask, page_table=page_table,
+                )
         x = x + seq_shard(y)
         if ac is not None:
             new_cache.update(ac)
@@ -698,6 +699,7 @@ def pad_caches(caches, target_len: int):
     return jax.tree_util.tree_map_with_path(pad, caches)
 
 
+@jax.named_scope("lm_head")
 def logits_from_features(params, x, cfg):
     if cfg.tie_embeddings:
         out = jnp.einsum("bsd,vd->bsv", x, params["embed"])
@@ -708,6 +710,7 @@ def logits_from_features(params, x, cfg):
     return out
 
 
+@jax.named_scope("loss")
 def chunked_cross_entropy(
     params, features, labels, cfg, *, num_chunks: int = 8
 ) -> jax.Array:

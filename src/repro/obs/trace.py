@@ -25,6 +25,11 @@ Design constraints, asserted by ``tests/test_obs.py``:
   counters are ``ph="C"``, typed audit events are instants (``ph="i"``)
   whose payload rides ``args``.  :func:`validate_events` is the shared
   schema check used by tests, CI and ``scripts/measure_run.py``.
+* **One clock with the device.**  While enabled, every span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name (its *mirror*), so a
+  profiler trace shows the program's spans on the host timeline beside the
+  device's operations.  The factory is bound when the tracer is enabled;
+  without JAX there is no mirror and this module never imports it.
 """
 
 from __future__ import annotations
@@ -66,7 +71,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """A live span: records a complete (``ph="X"``) event on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int, args: dict):
         self._tracer = tracer
@@ -81,6 +86,14 @@ class _Span:
         return self
 
     def __enter__(self):
+        # The mirror opens before the span's own clock starts and closes
+        # after it stops, so the profiler's copy encloses the tracer's.
+        factory = self._tracer._mirror
+        if factory is not None:
+            self._mirror = factory(self.name)
+            self._mirror.__enter__()
+        else:
+            self._mirror = None
         self._t0 = self._tracer._now_us()
         return self
 
@@ -110,7 +123,18 @@ class _Span:
             tr._events = events = events[-keep:]
         events.append(ev)
         lock.release()
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
         return False
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` where JAX is installed, else None."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class Tracer:
@@ -127,6 +151,13 @@ class Tracer:
         # ids collide with nothing in this range (ids start at 1).
         self._tracks: dict[str, int] = {}
         self._next_track = 1
+        # Span mirror factory (name -> context manager); None = no mirror.
+        self._mirror = None
+
+    def bind_mirror(self, factory) -> None:
+        """Mirror every span into ``factory(name)``, a context manager
+        entered and exited around the span (``None``: no mirror)."""
+        self._mirror = factory
 
     # -- time / track bookkeeping -------------------------------------------
     def _now_us(self) -> float:
@@ -265,6 +296,8 @@ def default() -> Tracer:
 
 
 def enable() -> Tracer:
+    if _DEFAULT._mirror is None:
+        _DEFAULT.bind_mirror(_profiler_annotation())
     _DEFAULT.enabled = True
     return _DEFAULT
 

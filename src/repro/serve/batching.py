@@ -94,6 +94,7 @@ def _fold_span_keys(base, rids, starts, offsets):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("kv.write")
 def _scatter_slot_caches(caches, one, slot):
     """Write ONE slot's column of every dense cache leaf.
 
@@ -112,6 +113,7 @@ def _scatter_slot_caches(caches, one, slot):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("kv.write")
 def _scatter_prompt_pages(caches, one, page_ids):
     """Scatter a batch-1 prefill's K/V into the page pool.
 
@@ -131,6 +133,7 @@ def _scatter_prompt_pages(caches, one, page_ids):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("kv.write")
 def _copy_pages(caches, src, dst):
     """Copy-on-write fork: duplicate pages ``src -> dst`` in every pool."""
     return jax.tree.map(lambda pool: pool.at[:, dst].set(pool[:, src]), caches)
@@ -364,17 +367,21 @@ class ContinuousBatcher:
                     self.queue.appendleft(req)
                     break
             admitted += 1
+            start = plan_a.start if plan_a is not None else 0
             if self.prefill_chunk > 0:
                 # Chunked prefill: reserve the slot, stream the prompt
                 # through the tick loop (see _advance_prefill) — starting
                 # past any prefix-cache hit.
-                start = plan_a.start if plan_a is not None else 0
                 self.prefilling.append(_Prefill(req, slot, pos=start))
                 continue
-            if self.paged:
-                load = self._admit_paged(req, slot, plan_a, load)
-            else:
-                load = self._admit_whole(req, slot, load)
+            # One span per admission: page allocation, prefill dispatch,
+            # page scatter, and the host read of the first token.
+            with self._tr.span("serve.admit", tid=self.trace_tid, rid=req.rid,
+                               tokens=len(req.prompt) - start):
+                if self.paged:
+                    load = self._admit_paged(req, slot, plan_a, load)
+                else:
+                    load = self._admit_whole(req, slot, load)
         return admitted, load
 
     def _admit_whole(self, req: Request, slot: int, load):
@@ -538,25 +545,29 @@ class ContinuousBatcher:
                 self._spec_tick(live)
             )
         elif live:
-            perm, wire = self._perm_args()
-            live_mask = np.zeros((self.slots, 1), np.float32)
-            live_mask[live] = 1.0
-            page_table = None
-            if self.paged:
-                # Every live slot writes position t[s] this tick; fork any
-                # shared page in range and allocate fresh pages on demand.
-                for s in live:
-                    self._apply_forks(
-                        self.alloc.ensure(s, int(self.t[s]), int(self.t[s]) + 1)
-                    )
-                page_table = jnp.asarray(self.alloc.table)
-            rng = self._span_keys(1)[:, 0] if self.sample else None
-            # The live mask serves two jobs (DESIGN.md §9): it weights the
-            # exported MoE gate telemetry, and it suppresses K/V writes for
-            # dead slots — without it the decode step would stomp a stale
-            # position of a slot that is empty or still mid-chunked-prefill.
+            # The decode span runs from the page bookkeeping to the tick's
+            # tokens and gate loads reaching the host.
             with self._tr.span("serve.decode", tid=self.trace_tid,
                                live=len(live)):
+                perm, wire = self._perm_args()
+                live_mask = np.zeros((self.slots, 1), np.float32)
+                live_mask[live] = 1.0
+                page_table = None
+                if self.paged:
+                    # Every live slot writes position t[s] this tick; fork
+                    # any shared page in range and allocate fresh pages on
+                    # demand.
+                    for s in live:
+                        self._apply_forks(
+                            self.alloc.ensure(s, int(self.t[s]), int(self.t[s]) + 1)
+                        )
+                    page_table = jnp.asarray(self.alloc.table)
+                rng = self._span_keys(1)[:, 0] if self.sample else None
+                # The live mask serves two jobs (DESIGN.md §9): it weights the
+                # exported MoE gate telemetry, and it suppresses K/V writes
+                # for dead slots — without it the decode step would stomp a
+                # stale position of a slot that is empty or still
+                # mid-chunked-prefill.
                 next_tok, self.caches, stats = self._step(
                     self.params,
                     self.caches,
@@ -568,26 +579,27 @@ class ContinuousBatcher:
                     jnp.asarray(live_mask),
                     page_table,
                 )
-            if stats is not None:
-                gate_load = np.asarray(stats)
-            next_np = np.asarray(next_tok)
-            for s in live:
-                req = self.active[s]
-                tok = int(next_np[s, 0])
-                req.out.append(tok)
-                self.t[s] += 1
-                self.tokens[s, 0] = tok
-                done = (
-                    len(req.out) >= req.max_new_tokens
-                    or (req.eos_id is not None and tok == req.eos_id)
-                    or self.t[s] >= self.max_len
-                )
-                if done:
-                    finished += 1
-                    self._finish(req)
-                    self.active[s] = None
-                    if self.paged:
-                        self.alloc.release(s)
+                if stats is not None:
+                    gate_load = np.asarray(stats)
+                next_np = np.asarray(next_tok)
+            with self._tr.span("serve.emit", tid=self.trace_tid):
+                for s in live:
+                    req = self.active[s]
+                    tok = int(next_np[s, 0])
+                    req.out.append(tok)
+                    self.t[s] += 1
+                    self.tokens[s, 0] = tok
+                    done = (
+                        len(req.out) >= req.max_new_tokens
+                        or (req.eos_id is not None and tok == req.eos_id)
+                        or self.t[s] >= self.max_len
+                    )
+                    if done:
+                        finished += 1
+                        self._finish(req)
+                        self.active[s] = None
+                        if self.paged:
+                            self.alloc.release(s)
         if self.paged:
             self.kv_resident_pages_peak = max(
                 self.kv_resident_pages_peak, self.alloc.resident_pages()

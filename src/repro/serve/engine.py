@@ -468,7 +468,8 @@ class ServeEngine:
         self._m_ticks.inc()
         self._m_tokens.inc(served)
         self._m_a2a.inc(self.a2a_bytes - a2a0)
-        self._observe(stats)
+        with self._tr.span("serve.observe", tid=self._tid):
+            self._observe(stats)
         self._maybe_reconfigure()
         self.tick_log.append(stats)
         return stats

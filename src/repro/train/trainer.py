@@ -343,8 +343,6 @@ class Trainer:
             self._m_steps.inc()
             self._m_tokens.inc(float(batch_np.tokens.size))
             self._m_step_time.observe(dt)
-            if self._tr.enabled:
-                self._tr.counter("train.step_time_s", dt, tid=tid)
             # Straggler watchdog (mitigation = flag + report; a real cluster
             # deployment feeds this to the job scheduler for hot-sparing).
             if self._ema_step_time is not None and dt > t.straggler_factor * self._ema_step_time:

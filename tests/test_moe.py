@@ -194,3 +194,34 @@ def test_dense_decode_matches_sparse_backends():
     np.testing.assert_allclose(
         np.asarray(st_d.expert_load), np.asarray(st_e.expert_load)
     )
+
+
+MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.combine")
+
+
+@pytest.mark.parametrize("backend,chunks", [("mixnet", 1), ("mixnet", 2), ("einsum", 1)],
+                         ids=["mixnet", "mixnet_chunked", "einsum"])
+def test_train_step_hlo_names_each_moe_stage(backend, chunks):
+    import dataclasses
+    import re
+
+    from repro.optim.adamw import AdamWConfig
+    from repro.train.train_step import init_all, make_train_step
+
+    cfg = make_cfg()
+    cfg = dataclasses.replace(
+        cfg, remat="full",
+        moe=dataclasses.replace(cfg.moe, backend=backend, overlap_chunks=chunks),
+    )
+    opt = AdamWConfig()
+    params, _, opt_state = init_all(KEY, cfg, PLAN, opt)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+    step = jax.jit(make_train_step(cfg, PLAN, opt))
+    hlo = step.lower(params, opt_state, batch).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in MOE_SCOPES:
+        named = [n for n in op_names if re.search(rf"(^|[/(]){re.escape(scope)}($|[/)])", n)]
+        # forward (or its recomputation) and backward both carry the name
+        assert any("transpose(" not in n for n in named), scope
+        assert any("transpose(" in n for n in named), scope

@@ -129,6 +129,86 @@ def test_tracer_ring_buffer_drops_oldest():
     assert tr._dropped > 0
 
 
+class _FakeAnnotation:
+    """Records each enter and exit of a span's mirror."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_enabled_spans_mirror_into_one_annotation_each_in_nesting_order():
+    _FakeAnnotation.log = []
+    tr = Tracer()
+    tr.bind_mirror(_FakeAnnotation)
+    tr.enabled = True
+    with tr.span("outer", step=1):
+        with tr.span("inner", rid=7):
+            pass
+        with tr.span("second"):
+            pass
+    assert _FakeAnnotation.log == [
+        ("enter", "outer"), ("enter", "inner"), ("exit", "inner"),
+        ("enter", "second"), ("exit", "second"), ("exit", "outer"),
+    ]
+    # the tracer's own record is unchanged: args stay out of the mirror
+    assert [e["name"] for e in tr.events()] == ["inner", "second", "outer"]
+    assert tr.events()[0]["args"] == {"rid": 7}
+
+
+def test_disabled_tracer_creates_no_mirror():
+    _FakeAnnotation.log = []
+    tr = Tracer()
+    tr.bind_mirror(_FakeAnnotation)
+    assert tr.span("a") is tr.span("b")
+    with tr.span("a"):
+        pass
+    assert _FakeAnnotation.log == []
+    assert tr.events() == []
+
+
+def test_enable_binds_the_profiler_annotation():
+    import jax.profiler
+
+    tr = trace.enable()
+    assert tr._mirror is jax.profiler.TraceAnnotation
+    with tr.span("serve.tick", tick=0):
+        pass
+    assert [e["name"] for e in tr.events()] == ["serve.tick"]
+
+
+def test_obs_imports_and_traces_without_jax():
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "sys.modules['jax'] = None\n"
+        "from repro import obs\n"
+        "from repro.obs import trace\n"
+        "tr = trace.enable()\n"
+        "assert tr._mirror is None\n"
+        "with tr.span('netsim.scenario'):\n"
+        "    pass\n"
+        "assert [e['name'] for e in tr.events()] == ['netsim.scenario']\n"
+        "assert not any(m == 'jax' or m.startswith('jax.') for m in sys.modules\n"
+        "               if sys.modules[m] is not None)\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, timeout=120)
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
@@ -310,6 +390,7 @@ def test_serve_bit_identical_with_tracing_and_trace_contents(tmp_path):
     evs = trace.default().events()
     names = {e["name"] for e in evs}
     assert "serve.tick" in names
+    assert {"serve.admit", "serve.decode", "serve.emit", "serve.observe"} <= names
     assert "controlplane.plan" in names
     assert "traffic.report" in names
     assert any(n.startswith("serve.") and e["cat"] == "decision"
@@ -375,3 +456,46 @@ def test_trainer_autotune_cache_miss_warns_and_counts(tmp_path, capsys):
     assert metrics.default().value("autotune.cache_miss") == 1.0
     out = capsys.readouterr().out
     assert "autotune cache miss" in out and "no-such-key" in out
+
+
+def _inside(span, outer):
+    return (span["tid"] == outer["tid"] and outer["ts"] <= span["ts"]
+            and span["ts"] + span["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_paged_serve_spans_one_admit_per_request_inside_ticks():
+    cfg = _moe_cfg()
+    params, _ = init_model(jax.random.PRNGKey(0), cfg, PLAN)
+    gen = WorkloadGenerator("chat", seed=5, vocab_size=cfg.vocab_size)
+    reqs = [
+        dataclasses.replace(r, prompt_len=min(r.prompt_len, 14),
+                            max_new_tokens=min(r.max_new_tokens, 4))
+        for r in gen.generate(4)
+    ]
+    scfg = ServeConfig(slots=2, max_len=40, paged=True, page_size=8,
+                       prefix_cache=False)
+
+    def run():
+        eng = ServeEngine(jax.tree.map(lambda a: a, params), cfg, PLAN, scfg)
+        eng.run(reqs, gen)
+        return eng
+
+    base = run()
+    trace.enable()
+    traced = run()
+    a = {r.rid: list(r.out) for r in base.batcher.finished}
+    b = {r.rid: list(r.out) for r in traced.batcher.finished}
+    assert a == b and len(b) == len(reqs), "tracing changed generated tokens"
+
+    evs = [e for e in trace.default().events() if e["ph"] == "X"]
+    admits = [e for e in evs if e["name"] == "serve.admit"]
+    prompts = {r.rid: len(r.prompt) for r in traced.batcher.finished}
+    assert sorted(e["args"]["rid"] for e in admits) == sorted(prompts)
+    assert all(e["args"]["tokens"] == prompts[e["args"]["rid"]] for e in admits)
+    ticks = [e for e in evs if e["name"] == "serve.tick"]
+    phases = [e for e in evs if e["name"] in
+              ("serve.admit", "serve.decode", "serve.emit", "serve.observe")]
+    assert {e["name"] for e in phases} == {"serve.admit", "serve.decode",
+                                           "serve.emit", "serve.observe"}
+    assert all(any(_inside(p, t) for t in ticks) for p in phases)
+    assert validate_events(evs) == []

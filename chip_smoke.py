@@ -36,6 +36,7 @@ import numpy as np
 from repro.configs.paper_models import MIXTRAL_8X7B_CONFIG
 from repro.data.pipeline import SyntheticLM
 from repro.kernels import ops, ref
+from repro.kernels.grouped_matmul import pick_tiles
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_model
@@ -152,7 +153,7 @@ def kernel_checks(tcfg, scfg, seed: int) -> None:
     e, k = tcfg.moe.num_experts, tcfg.moe.top_k
     hq, hkv, dh = tcfg.num_heads, tcfg.num_kv_heads, tcfg.resolved_head_dim
     t = TRAIN_BATCH * TRAIN_SEQ
-    blk = tcfg.moe.dispatch_block
+    blk = pick_tiles(t * k, e, d, f)[0]
     keys = iter(jax.random.split(jax.random.PRNGKey(seed), 32))
     bf16 = jnp.bfloat16
     hi = jax.default_matmul_precision("highest")
@@ -174,10 +175,11 @@ def kernel_checks(tcfg, scfg, seed: int) -> None:
     xb = jax.random.normal(next(keys), (n, blk, d), bf16)
     wg = (jax.random.normal(next(keys), (e, d, f), jnp.float32) * d**-0.5).astype(bf16)
     be = jnp.sort(jax.random.randint(next(keys), (n,), 0, e))
-    got = ops.grouped_matmul(xb, wg, block_experts=be)
+    used = jnp.int32(n - e)  # the last e tiles are skipped, as padding is
+    got = ops.grouped_matmul(xb, wg, block_experts=be, used_blocks=used)
     with hi:
-        _compare("grouped_matmul_blocks", got, ref.grouped_matmul_blocks(xb, wg, be),
-                 2.0**-6)
+        _compare("grouped_matmul_blocks", got,
+                 ref.grouped_matmul_blocks(xb, wg, be, used), 2.0**-6)
     del xb, wg
 
     logits = jax.random.normal(next(keys), (t, e), jnp.float32)

@@ -79,12 +79,15 @@ def _gmm_blocks_bwd(args, g):
     The oracle's own VJP differentiates a ``lax.scan`` that gathers one
     expert's weights per row tile, and would stack an f32 ``[D, F]`` weight
     residual per tile (hundreds of GB at published widths).  Here the tile
-    ownership becomes a one-hot ``[n, E]`` mask, so each gradient is one
-    contraction: ``dx[n] = g[n] @ w[e(n)]^T`` and
-    ``dw[e] = sum over tiles owned by e of x[n]^T g[n]``, both in f32."""
-    x, w, block_experts = args
+    ownership becomes a one-hot ``[n, E]`` mask, zero on the tiles past the
+    used count, so each gradient is one contraction:
+    ``dx[n] = g[n] @ w[e(n)]^T`` and ``dw[e] = sum over used tiles owned by
+    e of x[n]^T g[n]``, both in f32."""
+    x, w, block_experts, used_blocks = args
+    live = jnp.arange(x.shape[0]) < used_blocks
     onehot = (
-        block_experts[:, None] == jnp.arange(w.shape[0], dtype=jnp.int32)
+        (block_experts[:, None] == jnp.arange(w.shape[0], dtype=jnp.int32))
+        & live[:, None]
     ).astype(jnp.float32)  # [n, E]
     f32 = jnp.float32
     xe = jnp.einsum("nbd,ne->nbed", x.astype(f32), onehot)
@@ -95,13 +98,17 @@ def _gmm_blocks_bwd(args, g):
         dx.astype(x.dtype),
         dw.astype(w.dtype),
         np.zeros(block_experts.shape, jax.dtypes.float0),
+        np.zeros(jnp.shape(used_blocks), jax.dtypes.float0),
     )
 
 
-def grouped_matmul(x, w, *, block_experts=None, backend: str = "auto"):
+def grouped_matmul(
+    x, w, *, block_experts=None, used_blocks=None, backend: str = "auto"
+):
     """Per-expert GEMM.  ``block_experts=None``: capacity layout ``[E, C, D]``
     against ``w [E, D, F]``; with a ``[n]`` block->expert map: dropless block
-    layout ``[n, B, D]`` (rows of tile ``i`` use ``w[block_experts[i]]``).
+    layout ``[n, B, D]`` (rows of tile ``i`` use ``w[block_experts[i]]``;
+    tiles ``i >= used_blocks`` come out zero, and the kernel skips them).
     Pallas path: backward in XLA (the capacity layout through the oracle's
     VJP, the block layout through :func:`_gmm_blocks_bwd`)."""
     mode = _resolve_simple(backend)
@@ -114,11 +121,13 @@ def grouped_matmul(x, w, *, block_experts=None, backend: str = "auto"):
             )(x, w)
         return ref.grouped_matmul(x, w)
     if mode == "pallas":
+        if used_blocks is None:
+            used_blocks = x.shape[0]
         return _with_vjp(
             functools.partial(grouped_matmul_blocks_pallas, interpret=interpret),
             _gmm_blocks_bwd,
-        )(x, w, block_experts)
-    return ref.grouped_matmul_blocks(x, w, block_experts)
+        )(x, w, block_experts, jnp.asarray(used_blocks, jnp.int32))
+    return ref.grouped_matmul_blocks(x, w, block_experts, used_blocks)
 
 
 def moe_dispatch(x, src, *, backend: str = "auto"):

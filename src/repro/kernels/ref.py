@@ -35,9 +35,13 @@ def grouped_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
 
 
 def grouped_matmul_blocks(
-    x: jax.Array, w: jax.Array, block_experts: jax.Array
+    x: jax.Array,
+    w: jax.Array,
+    block_experts: jax.Array,
+    used_blocks: jax.Array | None = None,
 ) -> jax.Array:
-    """Block-wise grouped GEMM: ``[n, B, D] @ w[block_experts[n], D, F]``.
+    """Block-wise grouped GEMM: ``[n, B, D] @ w[block_experts[n], D, F]``,
+    tiles ``i >= used_blocks`` zero (``None``: every tile is used).
 
     Oracle for the dropless (MegaBlocks) layout: a ``lax.scan`` over row
     tiles gathers ONE expert's ``[D, F]`` weights per step, so peak memory
@@ -50,7 +54,10 @@ def grouped_matmul_blocks(
         return _, yb.astype(x.dtype)
 
     _, out = jax.lax.scan(step, None, (x, block_experts))
-    return out
+    if used_blocks is None:
+        return out
+    live = jnp.arange(x.shape[0]) < used_blocks
+    return jnp.where(live[:, None, None], out, jnp.zeros_like(out))
 
 
 def moe_dispatch(x: jax.Array, src: jax.Array) -> jax.Array:

@@ -38,10 +38,9 @@ class MoEConfig:
     # Token-dispatch semantics (repro.models.routing): "dropless" routes every
     # token (MegaBlocks-style sort-based layout, static shapes; capacity_factor
     # ignored) or "capacity" drops overflow beyond the capacity_factor buffers.
+    # The dropless layout's row-tile height follows the shapes
+    # (repro.kernels.grouped_matmul.pick_tiles).
     dispatch: str = "dropless"
-    # Row-tile height of the dropless block layout (the grouped GEMM's unit of
-    # expert ownership; 8 = f32 sublane minimum, raise towards 128 for MXU).
-    dispatch_block: int = 8
     # Chunked comm/compute overlap (repro.core.overlap, DESIGN.md §8): the
     # mixnet backend splits the token dim into this many chunks and
     # software-pipelines chunk k+1's dispatch a2a under chunk k's expert FFN

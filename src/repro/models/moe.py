@@ -67,6 +67,7 @@ assigned architecture (grok-1: 8 experts -> 16 virtual on a 16-wide axis).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -75,8 +76,10 @@ from jax.sharding import PartitionSpec as P
 from repro.core import overlap
 from repro.core.commruntime import AllToAll, CommSpec, fuse_pack, fuse_unpack
 from repro.kernels import ops
+from repro.kernels.grouped_matmul import pick_tiles
 from repro.models import routing
 from repro.models.routing import MoEStats, router_losses
+from repro.obs import metrics
 from repro.parallel.sharding import ShardingPlan, constrain, virtual_experts
 
 __all__ = [
@@ -225,6 +228,19 @@ def _moe_einsum(params, x, cfg, plan: ShardingPlan, mesh=None, expert_perm=None)
 # ---------------------------------------------------------------------------
 
 
+def _record_tiles(rows: int, experts: int, d: int, f: int, padded_rows: int):
+    """Record at trace time the dropless GEMM tiles :func:`pick_tiles` gave
+    ``rows`` routed rows over ``experts`` local experts, as the gauge
+    ``moe.gemm_tile`` (value: the static row tiles), one series per
+    compiled shape and GEMM direction (``d -> f`` up, ``f -> d`` down)."""
+    for din, dout in ((d, f), (f, d)):
+        blk, bf, bd = pick_tiles(rows, experts, din, dout)
+        metrics.gauge(
+            "moe.gemm_tile", rows=rows, experts=experts, d=din, f=dout,
+            B=blk, bf=bf, bd=bd, padded_rows=padded_rows,
+        ).set(padded_rows // blk)
+
+
 def _wire_perms(wire_perm, p_axis):
     """(dest_perm, src_perm) realizing a ControlPlane wire re-address.
 
@@ -313,22 +329,27 @@ def _moe_mixnet_local(
                 # Block layout: every valid received row is placed (the
                 # chunked pipeline uses it for capacity mode too — static
                 # shapes, and stage-2 never drops below the stage-1
-                # capacity bound).
+                # capacity bound).  The tile follows the shapes: the
+                # receive buffer holds p_axis x the rows this device sends.
+                rows, f = re.shape[0] // p_axis, w_in.shape[-1]
+                blk = pick_tiles(rows, ev_local, d, f)[0]
                 plan2 = routing.dropless_plan(
-                    re, rank2, counts2, valid, ev_local, e.dispatch_block
+                    re, rank2, counts2, valid, ev_local, blk
                 )
-                packed = ops.moe_dispatch(rx, plan2.src).reshape(
-                    -1, e.dispatch_block, d
-                )
+                _record_tiles(rows, ev_local, d, f, plan2.num_rows)
+                packed = ops.moe_dispatch(rx, plan2.src).reshape(-1, blk, d)
             else:
                 c2 = routing.capacity(re.shape[0], 1, ev_local, e.capacity_factor)
                 plan2 = routing.capacity_plan(re, rank2, valid, ev_local, c2)
                 packed = ops.moe_dispatch(rx, plan2.src).reshape(ev_local, c2, d)
         with jax.named_scope("moe.experts"):
-            be = plan2.block_experts if blocks else None
-            h = ops.grouped_matmul(packed, w_in, block_experts=be)
-            gt = ops.grouped_matmul(packed, w_gate, block_experts=be)
-            ye = ops.grouped_matmul(act(gt) * h, w_out, block_experts=be)
+            gmm = functools.partial(
+                ops.grouped_matmul, block_experts=plan2.block_experts,
+                used_blocks=plan2.used_blocks,
+            )
+            h = gmm(packed, w_in)
+            gt = gmm(packed, w_gate)
+            ye = gmm(act(gt) * h, w_out)
         with jax.named_scope("moe.combine"):
             back = ops.moe_dispatch(ye.reshape(plan2.num_rows, d), plan2.slot)
         return back, plan2.kept

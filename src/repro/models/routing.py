@@ -84,8 +84,9 @@ class DispatchPlan:
     ``src[p]`` is the flat choice occupying packed row ``p`` (-1 empty) —
     the two are inverse views of the same permutation.  ``num_rows`` is the
     static packed-buffer height; ``block_experts`` (dropless layouts only)
-    maps each ``block``-row tile to its owning expert for the grouped GEMM.
-    ``kept`` counts the placed choices (telemetry).
+    maps each ``block``-row tile to its owning expert for the grouped GEMM,
+    and ``used_blocks`` counts the leading tiles that hold rows (the GEMM
+    skips the rest).  ``kept`` counts the placed choices (telemetry).
     """
 
     slot: jax.Array  # [N] i32
@@ -93,6 +94,7 @@ class DispatchPlan:
     num_rows: int
     block_experts: jax.Array | None
     kept: jax.Array  # scalar
+    used_blocks: jax.Array | None = None  # scalar i32
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +283,8 @@ def dropless_plan(
     multiple of ``block`` rows so each ``block``-row tile is owned by exactly
     one expert (``block_experts`` drives the grouped GEMM's scalar-prefetch
     index map).  Static height: padding never exceeds ``E·(block-1)`` rows
-    regardless of the realized load split."""
+    regardless of the realized load split; the tiles past ``used_blocks``
+    hold no rows."""
     n = dest.shape[0]
     nblk = (n + num_buckets * (block - 1)) // block
     num_rows = nblk * block
@@ -299,7 +302,7 @@ def dropless_plan(
     ).astype(jnp.int32)
     return DispatchPlan(
         slot=slot, src=src, num_rows=num_rows, block_experts=block_experts,
-        kept=ok.sum(),
+        kept=ok.sum(), used_blocks=(ends[-1] // block).astype(jnp.int32),
     )
 
 

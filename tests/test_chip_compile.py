@@ -20,7 +20,7 @@ from repro.kernels.flash_attention import (
     flash_attention_pallas,
     paged_flash_decode_pallas,
 )
-from repro.kernels.grouped_matmul import grouped_matmul_blocks_pallas
+from repro.kernels.grouped_matmul import grouped_matmul_blocks_pallas, pick_tiles
 from repro.kernels.moe_dispatch import moe_combine_pallas, moe_dispatch_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.topk_gating import topk_gating_pallas
@@ -28,11 +28,28 @@ from repro.kernels.topk_gating import topk_gating_pallas
 # Mixtral-8x7B widths; the train smoke's tokens (4 x 1024), its 4 held
 # experts, and the serve smoke's paged pool (4 slots x 8 pages of 16).
 D, F, HQ, HKV, DH = 4096, 14336, 32, 8, 128
-T, K, E, BLK = 4096, 2, 4, 8
+T, K, E = 4096, 2, 4
 B, S = 4, 1024
 SLOTS, PAGE, PAGES = 4, 16, 8
-N_TILES = T * K // BLK + E
 I32, F32, BF16 = jnp.int32, jnp.float32, jnp.bfloat16
+
+
+def _gmm_blocks_specs(rows, experts, d, f):
+    """The dropless GEMM's operands at the tile the rule picks for ``rows``
+    routed rows (the static height ``dropless_plan`` gives)."""
+    blk = pick_tiles(rows, experts, d, f)[0]
+    n = (rows + experts * (blk - 1)) // blk
+    return [((n, blk, d), BF16), ((experts, d, f), BF16), ((n,), I32), ((), I32)]
+
+
+# The train step's w_in / w_gate (D -> F) and w_out (F -> D) calls, and the
+# serving prefill's largest bucket (a 512-token prompt over all 8 experts).
+GMM_SPECS = {
+    "": _gmm_blocks_specs(T * K, E, D, F),
+    "_down": _gmm_blocks_specs(T * K, E, F, D),
+    "_prefill": _gmm_blocks_specs(512 * K, 8, D, F),
+    "_prefill_down": _gmm_blocks_specs(512 * K, 8, F, D),
+}
 
 
 @pytest.fixture(scope="module")
@@ -63,10 +80,6 @@ KERNELS = {
     "moe_combine": (
         moe_combine_pallas, [((T * K, D), BF16), ((T, K), I32), ((T, K), F32)]
     ),
-    "grouped_matmul_blocks": (
-        grouped_matmul_blocks_pallas,
-        [((N_TILES, BLK, D), BF16), ((E, D, F), BF16), ((N_TILES,), I32)],
-    ),
     "topk_gating": (
         functools.partial(topk_gating_pallas, k=K), [((T, E), F32)]
     ),
@@ -76,6 +89,8 @@ KERNELS = {
     ),
     "rmsnorm": (rmsnorm_pallas, [((T, D), BF16), ((D,), BF16)]),
 }
+for _sfx, _specs in GMM_SPECS.items():
+    KERNELS[f"grouped_matmul_blocks{_sfx}"] = (grouped_matmul_blocks_pallas, _specs)
 for _c in (1, 4):
     KERNELS[f"paged_flash_decode_c{_c}"] = (
         paged_flash_decode_pallas,
@@ -106,9 +121,18 @@ GRADS = {
         ops.flash_attention, (0, 1, 2), KERNELS["flash_attention"][1]
     ),
     "grouped_matmul_blocks": (
-        lambda x, w, be: ops.grouped_matmul(x, w, block_experts=be),
+        lambda x, w, be, used: ops.grouped_matmul(
+            x, w, block_experts=be, used_blocks=used
+        ),
         (0, 1),
-        KERNELS["grouped_matmul_blocks"][1],
+        GMM_SPECS[""],
+    ),
+    "grouped_matmul_blocks_down": (
+        lambda x, w, be, used: ops.grouped_matmul(
+            x, w, block_experts=be, used_blocks=used
+        ),
+        (0, 1),
+        GMM_SPECS["_down"],
     ),
     "grouped_matmul": (
         ops.grouped_matmul, (0, 1), [((E, 512, D), BF16), ((E, D, F), BF16)]

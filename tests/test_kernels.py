@@ -185,6 +185,69 @@ def test_grouped_matmul_blocks(n, b, d, f, e, dtype):
     assert float(jnp.max(jnp.abs(expect.astype(jnp.float32) - direct))) < tol * d
 
 
+@pytest.mark.parametrize("case", ["partial", "empty_expert", "one_expert", "none_used"])
+def test_grouped_matmul_blocks_skips_unused_tiles(case):
+    """Tiles past ``dropless_plan``'s used count: the kernel skips them and
+    stores exact zeros, even where their rows hold data; the used tiles
+    match the oracle.  Two f- and two d-steps, so a skipped tile's grid
+    steps walk both axes."""
+    from repro.kernels.grouped_matmul import grouped_matmul_blocks_pallas
+    from repro.models import routing
+
+    e, b, d, f = 3, 8, 256, 256
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    dest = jax.random.randint(k1, (40,), 0, e)
+    valid = None
+    if case == "empty_expert":
+        dest = jnp.where(dest == 1, 2, dest)
+    elif case == "one_expert":
+        dest = jnp.ones_like(dest)
+    elif case == "none_used":
+        valid = jnp.zeros(dest.shape, bool)
+    rank, counts = routing.bucket_ranks(dest, e, valid=valid)
+    plan = routing.dropless_plan(dest, rank, counts, valid, e, b)
+    used = int(plan.used_blocks)
+    n = plan.num_rows // b
+    assert used == int(jnp.sum(-(-counts // b))) and used < n
+    x = jax.random.normal(k2, (n, b, d))  # data in every tile, used or not
+    w = jax.random.normal(k3, (e, d, f))
+    out = grouped_matmul_blocks_pallas(
+        x, w, plan.block_experts, plan.used_blocks, bf=128, bd=128, interpret=True
+    )
+    expect = ref.grouped_matmul_blocks(x, w, plan.block_experts, plan.used_blocks)
+    assert float(jnp.max(jnp.abs(out - expect))) < 1e-4 * d
+    assert float(jnp.max(jnp.abs(out[used:]))) == 0.0
+    if used:
+        assert float(jnp.min(jnp.max(jnp.abs(out[:used]), axis=(1, 2)))) > 0.0
+
+
+@pytest.mark.parametrize(
+    "rows,experts,d,f,want_b",
+    [
+        (8192, 4, 4096, 14336, 256),  # train step, w_in / w_gate
+        (8192, 4, 14336, 4096, 256),  # train step, w_out
+        (128, 8, 4096, 14336, 32),  # serving prefill, 64-token prompt
+        (384, 8, 4096, 14336, 64),  # 192-token prompt
+        (1024, 8, 4096, 14336, 256),  # 512-token prompt
+        (64, 4, 32, 48, 32),  # CPU tests' tiny layer
+        (16, 4, 32, 48, 8),
+        (3, 8, 16, 16, 8),
+    ],
+)
+def test_pick_tiles(rows, experts, d, f, want_b):
+    """The dropless tile rule: B from the rows per expert, within 8-256;
+    bf and bd from the widths alone, each dividing its width."""
+    from repro.kernels.grouped_matmul import pick_tiles
+
+    b, bf, bd = pick_tiles(rows, experts, d, f)
+    assert b == want_b and b >= 8
+    assert f % bf == 0 and d % bd == 0
+    if d >= 1024 and f >= 1024:  # Mixtral widths: 1 MB weight tiles
+        assert (bf, bd) == (512, 1024)
+    for r in (1, rows, 7 * rows, 1 << 20):
+        assert pick_tiles(r, experts, d, f)[1:] == (bf, bd)
+
+
 @pytest.mark.parametrize("t,d,p", [(16, 32, 24), (64, 128, 64), (8, 48, 40)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_moe_dispatch_kernel(t, d, p, dtype):
@@ -248,6 +311,14 @@ def _grad_case(name):
         return (lambda x, w, backend: ops.grouped_matmul(
                     x, w, block_experts=be, backend=backend),
                 (0, 1), (n(0, (6, 8, 32)), n(1, (3, 32, 48))))
+    if name == "grouped_matmul_blocks_padded":
+        # dropless_plan's layout: expert 1 owns no tile, the last two tiles
+        # are padding past the used count (their rows hold data here).
+        be = jnp.asarray([0, 0, 2, 2, 2, 2], jnp.int32)
+        return (lambda x, w, backend: ops.grouped_matmul(
+                    x, w, block_experts=be, used_blocks=jnp.int32(4),
+                    backend=backend),
+                (0, 1), (n(0, (6, 8, 32)), n(1, (3, 32, 48))))
     if name == "grouped_matmul":
         return (lambda x, w, backend: ops.grouped_matmul(x, w, backend=backend),
                 (0, 1), (n(0, (3, 16, 32)), n(1, (3, 32, 48))))
@@ -267,7 +338,8 @@ def _grad_case(name):
 @pytest.mark.parametrize(
     "name",
     ["flash_attention", "grouped_matmul_blocks", "grouped_matmul",
-     "moe_dispatch", "moe_combine", "topk_gating"],
+     "moe_dispatch", "moe_combine", "topk_gating",
+     "grouped_matmul_blocks_padded"],
 )
 def test_custom_vjp_grad_matches_oracle(name):
     """jax.grad through the Pallas op's custom_vjp (interpret mode) equals
@@ -285,6 +357,7 @@ def test_custom_vjp_grad_matches_oracle(name):
 
     for got, want in zip(grads("pallas"), grads("ref")):
         assert got.shape == want.shape
+        assert bool(jnp.all(jnp.isfinite(got)))
         scale = float(jnp.max(jnp.abs(want)))
         assert scale > 0.0
         assert float(jnp.max(jnp.abs(got - want))) <= 1e-5 * scale, name

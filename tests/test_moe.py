@@ -9,8 +9,11 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.kernels import ops
+from repro.kernels.grouped_matmul import pick_tiles
 from repro.models import moe as moe_mod
 from repro.models.config import ModelConfig, MoEConfig
+from repro.obs import metrics
 from repro.parallel.sharding import make_plan, virtual_experts
 
 KEY = jax.random.PRNGKey(0)
@@ -66,6 +69,32 @@ def test_dropless_never_drops():
     for backend in ("einsum", "mixnet"):
         _, stats = moe_mod.moe_apply(params, x, cfg, PLAN, backend=backend)
         assert float(stats.dropped_fraction) == 0.0, backend
+
+
+def test_mixnet_dropless_tile_from_shapes(monkeypatch):
+    """The mixnet layer's dropless GEMM takes its row tile from the shapes,
+    records it as the ``moe.gemm_tile`` gauge, and on the Pallas kernels
+    (interpret mode), which skip the unused tiles, matches the oracles."""
+    cfg = make_cfg()
+    params, _ = moe_mod.init_moe(KEY, cfg, PLAN)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
+    metrics.reset()
+    want, _ = moe_mod.moe_apply(params, x, cfg, PLAN, backend="mixnet")
+    rows = 2 * 16 * 2  # tokens x top-k
+    blk, bf, bd = pick_tiles(rows, 4, 32, 48)
+    padded = (rows + 4 * (blk - 1)) // blk * blk
+    labels = dict(rows=rows, experts=4, B=blk, padded_rows=padded)
+    assert metrics.default().value(
+        "moe.gemm_tile", d=32, f=48, bf=bf, bd=bd, **labels
+    ) == padded // blk
+    _, bf_down, bd_down = pick_tiles(rows, 4, 48, 32)
+    assert metrics.default().value(
+        "moe.gemm_tile", d=48, f=32, bf=bf_down, bd=bd_down, **labels
+    ) == padded // blk
+    metrics.reset()
+    monkeypatch.setattr(ops, "_resolve_simple", lambda backend: "pallas")
+    got, _ = moe_mod.moe_apply(params, x, cfg, PLAN, backend="mixnet")
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
 
 
 @given(seed=st.integers(0, 50))

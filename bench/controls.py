@@ -37,22 +37,24 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from bench import run as bench_run  # noqa: E402
-from bench.lib import clock, device, dims, program, spec, train, weights  # noqa: E402
+from bench.lib import arch, clock, device, program, spec, train, weights  # noqa: E402
 from bench.reference import checks  # noqa: E402
 
 
 def train_controls(cell, seed: int, faults: list[str]) -> dict:
     c, t = cell.config, cell.traffic
     gen = spec.traffic_module(cell)
-    view = dims.reference_view(c)
+    model, ref = arch.of(c, cell.root), arch.reference(c, cell.root)
+    view = model.reference_view(c)
     b1 = c["optimizer"]["b1"]
 
     def readings(precision, fault=None, against=None):
-        r = checks.train_readings(weights.make(seed, c), gen.stream(t, c, seed), view,
-                                  c["optimizer"], steps=train.CHECK_STEPS,
+        r = checks.train_readings(ref, weights.make(seed, c, model), gen.stream(t, c, seed),
+                                  view, c["optimizer"], steps=train.CHECK_STEPS,
                                   precision=precision, fault=fault, against=against,
                                   keep_first_moments=against is None)
-        r["change_norms"] = checks.diff_norms(r.pop("params"), weights.make(seed, c))
+        r["change_norms"] = checks.diff_norms(r.pop("params"),
+                                              weights.make(seed, c, model))
         return r
 
     def in_place_of_program(r):

@@ -1,9 +1,11 @@
 """The system under test, reached through its public entry points only.
 
-Maps a configuration file onto the program's ``ModelConfig`` and optimizer,
-hands it the bench's own weights (``bench/lib/weights.py``), and builds the
-``Trainer`` or ``ServeEngine`` a cell drives.  This is the one file of the
-benchmark that imports the program (``src/repro``).
+Maps a configuration file onto the program's optimizer, hands the program
+the bench's own weights (``bench/lib/weights.py``) in its parameter layout,
+and builds the ``Trainer`` or ``ServeEngine`` a cell drives.  The
+configuration's ``ModelConfig`` and the program's parameter layout come from
+its architecture module (``bench/lib/arch.py``), whose ``model_config`` is the
+only other place the benchmark imports the program (``src/repro``).
 """
 
 from __future__ import annotations
@@ -11,52 +13,11 @@ from __future__ import annotations
 import pathlib
 import sys
 
-from bench.lib import dims
-
 
 def import_program(root: pathlib.Path) -> None:
     src = str(pathlib.Path(root) / "src")
     if src not in sys.path:
         sys.path.insert(0, src)
-
-
-def model_config(c: dict):
-    """The program's ``ModelConfig`` for configuration file ``c``.
-
-    The router's auxiliary losses stay out of the objective: the source
-    config sets ``output_router_logits`` false, so its
-    ``router_aux_loss_coef`` is not applied, and Mixtral has no z-loss."""
-    from repro.models.config import ModelConfig, MoEConfig
-
-    s = c["system"]
-    return ModelConfig(
-        name=c["name"],
-        family="moe",
-        num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"],
-        head_dim=dims.head_dim(c),
-        moe=MoEConfig(
-            num_experts=c["num_local_experts"],
-            top_k=c["num_experts_per_tok"],
-            d_ff=c["intermediate_size"],
-            backend=s["moe_backend"],
-            dispatch=s["dispatch"],
-            decode_backend=s["decode_backend"],
-            balance_loss=c["router_aux_loss_coef"] if c["output_router_logits"] else 0.0,
-            router_z_loss=0.0,
-        ),
-        act=c["hidden_act"],
-        dtype=c["torch_dtype"],
-        rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        remat=s["remat"],
-        opt_moment_dtype=c.get("optimizer", {}).get("moment_dtype", "float32"),
-    )
 
 
 def optimizer(c: dict):
@@ -71,17 +32,6 @@ def optimizer(c: dict):
     )
 
 
-def to_program(w: dict) -> dict:
-    """The bench's weight tree in the program's parameter layout (the same
-    leaves, nested as ``init_model`` nests them: one scanned block kind)."""
-    return {
-        "embed": w["embed"],
-        "blocks": {"0_global": w["layers"]},
-        "final_norm": w["final_norm"],
-        "lm_head": w["lm_head"],
-    }
-
-
 def _same_layout(got, want) -> None:
     import jax
 
@@ -93,17 +43,17 @@ def _same_layout(got, want) -> None:
                              f"{b.shape}/{b.dtype}")
 
 
-def trainer(c: dict, w: dict, *, total_steps: int):
+def trainer(model, c: dict, w: dict, *, total_steps: int):
     """A one-chip ``Trainer`` for ``c`` whose parameters are the bench's
-    weights ``w``."""
+    weights ``w``; ``model`` is ``c``'s architecture module."""
     import jax
 
     from repro.parallel.sharding import make_plan
     from repro.train.trainer import Trainer, TrainerConfig
 
     tcfg = TrainerConfig(total_steps=total_steps)
-    tr = Trainer(model_config(c), optimizer(c), tcfg, make_plan(None))
-    params = to_program(w)
+    tr = Trainer(model.model_config(c), optimizer(c), tcfg, make_plan(None))
+    params = model.to_program(w)
     _same_layout(params, tr.params)
     tr.params = params
     jax.block_until_ready(tr.params)
@@ -117,17 +67,19 @@ def train_one_step(tr, batches) -> dict:
     return tr.train(batches)[-1]
 
 
-def serve_engine(c: dict, w: dict, *, slots: int, max_len: int):
-    """A ``ServeEngine`` over the bench's weights ``w`` (greedy decoding)."""
+def serve_engine(model, c: dict, w: dict, *, slots: int, max_len: int):
+    """A ``ServeEngine`` over the bench's weights ``w`` (greedy decoding);
+    ``model`` is ``c``'s architecture module.  The K/V cache is paged unless
+    the configuration's ``system`` sets ``paged`` false."""
     from repro.parallel.sharding import make_plan
     from repro.serve.engine import ServeConfig, ServeEngine
 
     s = c["system"]
     scfg = ServeConfig(
-        slots=slots, max_len=max_len, paged=True, page_size=s["page_size"],
+        slots=slots, max_len=max_len, paged=s.get("paged", True), page_size=s["page_size"],
         prefix_cache=s["prefix_cache"], sample=False,
     )
-    return ServeEngine(to_program(w), model_config(c), make_plan(None), scfg)
+    return ServeEngine(model.to_program(w), model.model_config(c), make_plan(None), scfg)
 
 
 def request(rid: int, prompt, max_new: int):

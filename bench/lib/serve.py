@@ -19,7 +19,7 @@ import gc
 
 import numpy as np
 
-from bench.lib import clock, dims, profile, program, weights
+from bench.lib import arch, clock, profile, program, weights
 from bench.lib.record import Record, RequestLog, Tick
 from bench.reference import checks
 
@@ -113,7 +113,8 @@ def run(ctx) -> Record:
     cell, seed = ctx.cell, ctx.seed
     c, t = cell.config, cell.traffic
     gen = ctx.traffic
-    engine = program.serve_engine(c, weights.make(seed, c), slots=t["slots"],
+    model = arch.of(c, cell.root)
+    engine = program.serve_engine(model, c, weights.make(seed, c, model), slots=t["slots"],
                                   max_len=gen.max_len(t))
     pool = gen.requests(t, c, seed)
     loop = Loop(engine, pool)
@@ -185,14 +186,15 @@ def run(ctx) -> Record:
     # Free the engine, then the reference reads the sample.
     del engine, loop
     gc.collect()
-    w = weights.make(seed, c)
-    view, pad_to, far = dims.reference_view(c), int(limits["pad_to"]), float(limits["far_gap"])
-    gaps = checks.serve_gaps(w, pairs, view, pad_to=pad_to)
+    w = weights.make(seed, c, model)
+    ref = arch.reference(c, cell.root)
+    view, pad_to, far = model.reference_view(c), int(limits["pad_to"]), float(limits["far_gap"])
+    gaps = checks.serve_gaps(ref, w, pairs, view, pad_to=pad_to)
     if gaps:
         rec.checks = {"far_token_share": far_share(gaps, far)}
         rec.counts["widest_logit_gap"] = max(gaps)
     if ctx.control and pairs:
-        fp8 = checks.serve_gaps(w, pairs, view, pad_to=pad_to, precision="fp8")
+        fp8 = checks.serve_gaps(ref, w, pairs, view, pad_to=pad_to, precision="fp8")
         rec.counts["control_fp8"] = gap_summary(fp8)
         rec.counts["program"] = gap_summary(gaps)
     return rec

@@ -8,7 +8,9 @@ a file of its own, found here by name:
   whose ``kind`` names the generator ``bench/traffic/<kind>.py``;
 * ``bench/limits/<cell>.json``               — the limits of the cell's
   correctness comparison;
-* ``bench/metrics/<metric>.py``              — one reader per metric.
+* ``bench/metrics/<metric>.py``              — one reader per metric;
+* ``bench/models/<model_type>.py``           — the architecture module of a
+  configuration, by the file's ``model_type`` (``bench/lib/arch.py``).
 
 The harness names no cell, configuration or metric itself.
 """

@@ -18,7 +18,7 @@ import gc
 
 import numpy as np
 
-from bench.lib import clock, dims, profile, program, weights
+from bench.lib import arch, clock, profile, program, weights
 from bench.lib.record import Record, Step
 from bench.reference import checks
 
@@ -32,11 +32,6 @@ CHECKS = ("loss_gap", "grad_norm_gap", "grad_diff", "first_change_norm_gap",
           "change_norm_gap", "mu_norm_gap", "nu_norm_gap", "mu_median_gap", "nu_median_gap")
 
 
-def canonical(paths: dict[str, float]) -> dict[str, float]:
-    """Program leaf paths in the bench's weight layout."""
-    return {p.replace("blocks/0_global/", "layers/"): v for p, v in paths.items()}
-
-
 def relative_gaps(got: dict[str, float], want: dict[str, float],
                   keep=None) -> dict[str, float]:
     """Per leaf ``|got - want|`` over the larger of ``want`` and the median
@@ -46,13 +41,13 @@ def relative_gaps(got: dict[str, float], want: dict[str, float],
     return {k: abs(got[k] - want[k]) / max(want[k], med, 1e-30) for k in leaves}
 
 
-def canonical_arrays(tree) -> dict:
+def canonical_arrays(model, tree) -> dict:
     """Host copies of a program tree's leaves, by their paths in the bench's
-    weight layout."""
+    weight layout (``model``: the architecture module)."""
     import jax
 
-    return canonical({p: np.asarray(x) for p, x in
-                      zip(checks.leaf_paths(tree), jax.tree.leaves(tree))})
+    return model.canonical({p: np.asarray(x) for p, x in
+                            zip(checks.leaf_paths(tree), jax.tree.leaves(tree))})
 
 
 def compare(prog: dict, ref: dict) -> dict[str, float]:
@@ -97,8 +92,10 @@ def run(ctx) -> Record:
     cell, seed = ctx.cell, ctx.seed
     c, t = cell.config, cell.traffic
     b1 = c["optimizer"]["b1"]
+    model = arch.of(c, cell.root)
+    canonical = model.canonical
 
-    tr = program.trainer(c, weights.make(seed, c), total_steps=CHECK_STEPS)
+    tr = program.trainer(model, c, weights.make(seed, c, model), total_steps=CHECK_STEPS)
     feed = ctx.traffic.stream(t, c, seed)
     steps: list[Step] = []
 
@@ -117,11 +114,11 @@ def run(ctx) -> Record:
         if i == 0:
             mu = checks.leaf_norms(tr.opt_state["mu"])
             prog["grad_norms"] = canonical({k: v / (1 - b1) for k, v in mu.items()})
-            first_moments = canonical_arrays(tr.opt_state["mu"])
-            init = program.to_program(weights.make(seed, c))
+            first_moments = canonical_arrays(model, tr.opt_state["mu"])
+            init = model.to_program(weights.make(seed, c, model))
             prog["first_change_norms"] = canonical(checks.diff_norms(tr.params, init))
             del init
-    init = program.to_program(weights.make(seed, c))
+    init = model.to_program(weights.make(seed, c, model))
     prog["change_norms"] = canonical(checks.diff_norms(tr.params, init))
     del init
     prog["mu_norms"] = canonical(checks.leaf_norms(tr.opt_state["mu"]))
@@ -159,11 +156,12 @@ def run(ctx) -> Record:
     del tr, feed
     gc.collect()
     ref = checks.train_readings(
-        weights.make(seed, c), ctx.traffic.stream(t, c, seed), dims.reference_view(c),
-        c["optimizer"], steps=CHECK_STEPS, against=first_moments,
+        arch.reference(c, cell.root), weights.make(seed, c, model),
+        ctx.traffic.stream(t, c, seed), model.reference_view(c), c["optimizer"],
+        steps=CHECK_STEPS, against=first_moments,
     )
     del first_moments
-    ref["change_norms"] = checks.diff_norms(ref.pop("params"), weights.make(seed, c))
+    ref["change_norms"] = checks.diff_norms(ref.pop("params"), weights.make(seed, c, model))
     got = compare(prog, ref)
     rec.counts["program_losses"] = prog["losses"]
     rec.counts["reference_losses"] = ref["losses"]

@@ -14,7 +14,9 @@ Serving: the reference runs once over each sampled prompt followed by the
 tokens the program served, and reads how far below its own best logit each
 served token's logit lies.
 
-Nothing here imports the system under test.
+The reference itself is the configuration's (``bench/lib/arch.py``
+``reference``), passed in as ``ref``: a module with ``features``, ``logits``
+and ``loss``.  Nothing here imports the system under test.
 """
 
 from __future__ import annotations
@@ -24,8 +26,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench.reference import moe_lm
 
 F32 = jnp.float32
 
@@ -78,12 +78,12 @@ def _loss_mask(labels, fault):
     return mask
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "precision", "fault"))
-def _grads(params, tokens, labels, *, cfg, precision, fault):
+@functools.partial(jax.jit, static_argnames=("ref", "cfg", "precision", "fault"))
+def _grads(params, tokens, labels, *, ref, cfg, precision, fault):
     """Loss, float32 gradient of every leaf (whatever dtype it is stored
     in), and their global norm."""
     mask = _loss_mask(labels, fault)
-    loss, grads = jax.value_and_grad(moe_lm.loss)(
+    loss, grads = jax.value_and_grad(ref.loss)(
         jax.tree.map(lambda a: a.astype(F32), params), tokens, labels, dict(cfg), precision,
         mask=mask)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads)))
@@ -124,10 +124,10 @@ def _gap_norm(first_moment, g, scale, b1):
     return jnp.sqrt(jnp.sum(jnp.square(first_moment.astype(F32) / (1.0 - b1) - g * scale)))
 
 
-def train_readings(weights, batches, cfg: dict, opt: dict, *, steps: int,
+def train_readings(ref, weights, batches, cfg: dict, opt: dict, *, steps: int,
                    precision: str = "f32", fault: str | None = None,
                    against: dict | None = None, keep_first_moments: bool = False) -> dict:
-    """The reference's readings over its first ``steps`` updates.
+    """The reference ``ref``'s readings over its first ``steps`` updates.
 
     ``weights`` are the stored initial weights (consumed); ``batches``
     yields objects with ``tokens``/``labels``.  Returns ``losses`` (one per
@@ -158,7 +158,7 @@ def train_readings(weights, batches, cfg: dict, opt: dict, *, steps: int,
         b = next(batches)
         loss, grads, gnorm = _grads(
             jax.tree.unflatten(tree, flat), jnp.asarray(b.tokens), jnp.asarray(b.labels),
-            cfg=frozen_cfg, precision=precision, fault=fault,
+            ref=ref, cfg=frozen_cfg, precision=precision, fault=fault,
         )
         scale = jnp.minimum(1.0, opt["clip_norm"] / (gnorm + 1e-9))
         grads = jax.tree.leaves(grads)
@@ -191,23 +191,24 @@ def train_readings(weights, batches, cfg: dict, opt: dict, *, steps: int,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "precision"))
-def _position_logits(weights, tokens, *, cfg, precision):
-    feats = moe_lm.features(weights, tokens, dict(cfg), precision)
-    return moe_lm.logits(weights, feats, precision)[0]  # [S, V]
+@functools.partial(jax.jit, static_argnames=("ref", "cfg", "precision"))
+def _position_logits(weights, tokens, *, ref, cfg, precision):
+    feats = ref.features(weights, tokens, dict(cfg), precision)
+    return ref.logits(weights, feats, precision)[0]  # [S, V]
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _gaps(weights, tokens, targets, *, cfg):
-    lg = _position_logits(weights, tokens, cfg=cfg, precision="f32")
+@functools.partial(jax.jit, static_argnames=("ref", "cfg"))
+def _gaps(weights, tokens, targets, *, ref, cfg):
+    lg = _position_logits(weights, tokens, ref=ref, cfg=cfg, precision="f32")
     best = jnp.max(lg, axis=-1)
     got = jnp.take_along_axis(lg, jnp.maximum(targets, 0)[:, None], axis=-1)[:, 0]
     return jnp.where(targets >= 0, best - got, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "precision"))
-def _top(weights, tokens, *, cfg, precision):
-    return jnp.argmax(_position_logits(weights, tokens, cfg=cfg, precision=precision), -1)
+@functools.partial(jax.jit, static_argnames=("ref", "cfg", "precision"))
+def _top(weights, tokens, *, ref, cfg, precision):
+    return jnp.argmax(_position_logits(weights, tokens, ref=ref, cfg=cfg, precision=precision),
+                      -1)
 
 
 def _padded(prompt, served, pad_to: int):
@@ -220,10 +221,10 @@ def _padded(prompt, served, pad_to: int):
     return tokens, targets
 
 
-def serve_gaps(weights, sample, cfg: dict, *, pad_to: int,
+def serve_gaps(ref, weights, sample, cfg: dict, *, pad_to: int,
                precision: str = "f32") -> list[float]:
-    """Per served token: reference best logit minus reference logit of the
-    token.  ``sample`` is ``[(prompt, served tokens)]``.  With
+    """Per served token: the reference ``ref``'s best logit minus its logit
+    of the token.  ``sample`` is ``[(prompt, served tokens)]``.  With
     ``precision="fp8"`` the token read is the one the fp8 forward puts
     first at that position (the control), not the served one."""
     frozen = tuple(sorted(cfg.items()))
@@ -231,10 +232,12 @@ def serve_gaps(weights, sample, cfg: dict, *, pad_to: int,
     for prompt, served in sample:
         tokens, targets = _padded(prompt, served, pad_to)
         if precision != "f32":
-            top = np.asarray(_top(weights, jnp.asarray(tokens), cfg=frozen, precision=precision))
+            top = np.asarray(_top(weights, jnp.asarray(tokens), ref=ref, cfg=frozen,
+                                  precision=precision))
             # The token the control would emit at each served position: its
             # own first choice there, given the served prefix.
             targets = np.where(targets >= 0, top, -1).astype(np.int32)
-        gaps = np.asarray(_gaps(weights, jnp.asarray(tokens), jnp.asarray(targets), cfg=frozen))
+        gaps = np.asarray(_gaps(weights, jnp.asarray(tokens), jnp.asarray(targets), ref=ref,
+                                cfg=frozen))
         out.extend(float(g) for g in gaps[targets >= 0])
     return out

@@ -2,10 +2,11 @@
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell, its configuration, its traffic mix, its limits and its metrics are
-found by name through ``BENCHMARK.json`` (``bench/lib/spec.py``); nothing
-here names one.  ``--trace 0`` prints the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics read from a profiled window.
+The cell, its configuration and architecture module, its traffic mix, its
+limits and its metrics are found by name through ``BENCHMARK.json``
+(``bench/lib/spec.py``, ``bench/lib/arch.py``); nothing here names one.
+``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics read from a profiled window.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also

@@ -1,4 +1,5 @@
-"""The FLOP and byte counts of the metric readers, against hand-worked
+"""The FLOP and byte counts that the metric readers take from the
+architecture module (``bench/models/mixtral.py``), against hand-worked
 Mixtral-8x7B numbers (d 4096, 32 query and 8 K/V heads of 128, expert width
 14336, top-2, vocabulary 32000, bf16)."""
 
@@ -7,7 +8,7 @@ import pathlib
 
 import pytest
 
-from bench.lib import spec
+from bench.lib import arch
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
@@ -16,8 +17,9 @@ def config(name):
     return json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
 
 
-def reader_module(metric):
-    return spec.load_module(ROOT / "bench" / "metrics" / f"{metric}.py", metric)
+def counts(c):
+    """The architecture module of configuration ``c``, which holds its counts."""
+    return arch.of(c, ROOT)
 
 
 def test_train_model_flops_per_token():
@@ -25,7 +27,8 @@ def test_train_model_flops_per_token():
     # router 4096*4 = 16,384; two experts 2*3*4096*14336 = 352,321,536.
     # Head 4096*32000 = 131,072,000.  6 * (394,280,960 + 131,072,000)
     # = 3,152,117,760, plus causal attention 6 * 4096 * 4096 = 100,663,296.
-    fpt = reader_module("mfu.train").flops_per_token(config("mixtral-8x7b-train-1chip"), 4096)
+    c = config("mixtral-8x7b-train-1chip")
+    fpt = counts(c).flops_per_token(c, 4096)
     assert fpt == 3_252_781_056
 
 
@@ -33,8 +36,8 @@ def test_grouped_gemm_call_bounds():
     # 4096 tokens x top-2 = 8192 rows: 2 * 8192 * 4096 * 14336 FLOPs;
     # 4 experts' 4096 x 14336 weights once plus 8192 rows of 4096 in and
     # 14336 out, all bf16: 2 * (234,881,024 + 150,994,944) bytes.
-    mod = reader_module("moe_gemm.roofline_share.train")
-    flops, nbytes = mod.call_bounds(config("mixtral-8x7b-train-1chip"), 4096)
+    c = config("mixtral-8x7b-train-1chip")
+    flops, nbytes = counts(c).call_bounds(c, 4096)
     assert flops == 962_072_674_304
     assert nbytes == 771_751_936
     # On a v5e (197 TFLOP/s, 819 GB/s) the FLOPs bound the call: 4.88 ms.
@@ -50,13 +53,13 @@ def test_serve_tick_bounds():
     # Held bytes: per layer (41,943,040 + 8*3*4096*14336) * 2 + router
     # 4096*8*4 = 2,902,589,440; two layers plus the 262,144,000-byte head =
     # 6,067,322,880; K/V read: 64,000 positions * 2 layers * 2 * 8 * 128 * 2.
-    mod = reader_module("mfu.serve")
-    flops, nbytes = mod.tick_bounds(config("mixtral-8x7b-serve-1chip"), 64, 64_000, [])
+    c = config("mixtral-8x7b-serve-1chip")
+    flops, nbytes = counts(c).tick_bounds(c, 64, 64_000, [])
     assert flops == 119_814_488_064
     assert nbytes == 6_067_322_880 + 524_288_000
     # A 256-token prompt adds 2*256*2*394,297,344 + 2*4096*32000
     # + 2*4096*2*256^2 FLOPs.
-    flops_p, _ = mod.tick_bounds(config("mixtral-8x7b-serve-1chip"), 64, 64_000, [256])
+    flops_p, _ = counts(c).tick_bounds(c, 64, 64_000, [256])
     assert flops_p - flops == 403_760_480_256 + 262_144_000 + 1_073_741_824
 
 

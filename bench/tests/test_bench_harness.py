@@ -51,9 +51,9 @@ def test_new_config_cell_and_metric_are_found_by_name(tmp_path, compile_cache):
     root = make_root(tmp_path)
     bench = root / "bench"
     cfg = json.loads((bench / "configs" / "tiny-moe.json").read_text())
-    cfg["name"] = "tiny-moe-wide-ffn"
-    cfg["intermediate_size"] = 2 * cfg["intermediate_size"]
-    (bench / "configs" / "tiny-moe-wide-ffn.json").write_text(json.dumps(cfg))
+    cfg["name"] = "tiny-moe-wide-vocab"
+    cfg["vocab_size"] = 2 * cfg["vocab_size"]
+    (bench / "configs" / "tiny-moe-wide-vocab.json").write_text(json.dumps(cfg))
     traffic = json.loads((bench / "traffic" / "tiny-lm.json").read_text())
     traffic["seq_len"] = 16
     (bench / "traffic" / "tiny-lm-short.json").write_text(json.dumps(traffic))
@@ -61,10 +61,10 @@ def test_new_config_cell_and_metric_are_found_by_name(tmp_path, compile_cache):
     (bench / "metrics" / "steps_seen.py").write_text(
         "def read(rec):\n    return len(rec.steps_in_window()) or None\n")
     spec = json.loads((root / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "tiny-moe-wide-ffn", "source": "tests",
-                            "file": "bench/configs/tiny-moe-wide-ffn.json", "reduced": [],
+    spec["configs"].append({"name": "tiny-moe-wide-vocab", "source": "tests",
+                            "file": "bench/configs/tiny-moe-wide-vocab.json", "reduced": [],
                             "why": "tests"})
-    spec["workloads"].append({"name": "tiny-new", "config": "tiny-moe-wide-ffn",
+    spec["workloads"].append({"name": "tiny-new", "config": "tiny-moe-wide-vocab",
                               "traffic": "tiny-lm-short", "chips": 1, "why": "tests"})
     spec["end_to_end"].append({"name": "steps_seen", "unit": "steps", "better": "higher",
                                "bound": 0.05, "source": "host_clock", "workloads": ["tiny-new"]})

@@ -160,7 +160,7 @@ def test_program_names_leave_every_existing_reading_unchanged():
     # The recorded trace of the parent program holds no program span or
     # scope: read with the program's names, busy time, operation times, the
     # breakdown and every existing trace metric come out the same.
-    from bench.lib import program_trace
+    from bench.lib import program_trace, spec
     from bench.lib.record import Record
 
     events = profile.load_saved(DATA / "trace-serve-v5e.json.gz")
@@ -172,10 +172,11 @@ def test_program_names_leave_every_existing_reading_unchanged():
     assert named.scoped_busy_share() == 0.0
     assert named.span_count("serve.tick") == 0
 
+    # The train cell's configuration: its model_type finds the counts.
+    config = spec.read_json(ROOT / "bench" / "configs" / "mixtral-8x7b-train-1chip.json")
+
     def record(trace):
-        rec = Record({"hidden_size": 4096, "intermediate_size": 14336,
-                      "num_experts_per_tok": 2, "num_local_experts": 4},
-                     {"seq_len": 4096, "batch": 1},
+        rec = Record(config, {"seq_len": 4096, "batch": 1},
                      {"bf16_flops": 1.97e14, "hbm_bytes_per_s": 8.19e11}, 1, (0.0, 1.0), 0.0)
         rec.trace = trace
         return rec
